@@ -20,8 +20,9 @@ being lintable here fails the ``kernel_primitives`` rule's
                          are stationary (tick outputs re-feed as inputs with
                          identical avals; no weak types anywhere).
 4. ``vmem_budget``     — the VMEM-resident pack operands recovered from each
-                         lowered ``pallas_call`` (pinned planes + prefetch
-                         rows) fit the planner's own budget:
+                         lowered ``pallas_call`` (pinned VMEM planes; SMEM
+                         metadata and prefetch rows live in scalar memory)
+                         fit the planner's own budget:
                          ``PackLayout/QuantPackLayout.vmem()``,
                          ``PackPlan.vmem()`` (+ the documented device
                          lane-padding allowance), and the per-shard
@@ -129,13 +130,18 @@ ALL_MODES = tuple(TABLE_MODES) + ("exact",)
 # --------------------------------------------------------------------------------------
 
 # Frozen from the lowered kernel bodies at enrollment time (comparator-plane
-# select + gather/FMA arithmetic; ``pjit`` covers jnp.clip/take sub-calls;
-# ``get``/``swap`` are the pallas ref reads/writes).
+# select + gather/FMA arithmetic; ``jit`` covers jnp.clip/take sub-calls;
+# ``get``/``swap`` are the pallas ref reads/writes; ``select_n`` is the
+# shared selector's running compare-and-select).
 _BASE = frozenset({
     "add", "broadcast_in_dim", "convert_element_type", "floor", "gather",
-    "ge", "get", "max", "min", "mul", "pjit", "reduce_sum", "slice", "sub",
-    "swap",
+    "ge", "get", "jit", "max", "min", "mul", "reduce_sum", "select_n",
+    "slice", "sub", "swap",
 })
+# table_pack's values-plane read: row/lane split of the address, per-row
+# lane gather (take_along_axis), row select and the lane-chunk concatenation
+_PLANE = frozenset({"and", "concatenate", "eq", "reshape",
+                    "shift_right_arithmetic"})
 # grad kernels add the in-domain mask (d/dx of the clamp epilogue)
 _GRAD = frozenset({"and", "lt"})
 # masked multi-member select (sharded owners, quant/poly width groups)
@@ -153,8 +159,8 @@ _FOLD = frozenset({
 KERNEL_ALLOWED: Dict[str, frozenset] = {
     "_table_kernel": _BASE,
     "_table_grad_kernel": _BASE | _GRAD,
-    "_pack_kernel": _BASE,
-    "_pack_grad_kernel": _BASE | _GRAD,
+    "_pack_kernel": _BASE | _PLANE,
+    "_pack_grad_kernel": _BASE | _PLANE | _GRAD,
     "_quant_kernel": _BASE,
     "_quant_grad_kernel": _BASE | _GRAD,
     "_poly_kernel": _BASE,

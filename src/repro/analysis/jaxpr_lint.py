@@ -25,13 +25,8 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tupl
 import jax
 import numpy as np
 from jax import core as jax_core
-
-try:  # the raw Jaxpr type moved around across jax versions
-    from jax._src.core import Jaxpr as _Jaxpr
-    from jax._src.core import ClosedJaxpr as _ClosedJaxpr
-except ImportError:  # pragma: no cover - version drift guard
-    _Jaxpr = type(None)
-    _ClosedJaxpr = type(None)
+from jax._src.core import ClosedJaxpr as _ClosedJaxpr
+from jax._src.core import Jaxpr as _Jaxpr
 
 # Dtypes that must never appear in a runtime trace: the design layer
 # (core/design.py, core/quantize.py) works in f64 on purpose, and a single
@@ -99,7 +94,7 @@ def iter_avals(jaxpr) -> Iterator[Tuple[str, Any]]:
         for v in eqn.invars:
             aval = getattr(v, "aval", None)
             if aval is None and hasattr(v, "val"):  # Literal
-                aval = jax_core.get_aval(v.val)
+                aval = jax.typeof(v.val)
             if aval is not None:
                 yield (name, aval)
         for v in eqn.outvars:
@@ -152,11 +147,8 @@ def pallas_eqns(traced) -> List[Any]:
 
 
 def kernel_name(eqn) -> str:
-    """The kernel body's registered name (``name_and_src_info`` in jax 0.4)."""
-    info = eqn.params.get("name_and_src_info")
-    if info is not None:
-        return getattr(info, "name", str(info))
-    return str(eqn.params.get("name", "<pallas>"))  # pragma: no cover
+    """The kernel body's function name (the body jaxpr's debug info)."""
+    return kernel_body(eqn).debug_info.func_name
 
 
 def kernel_body(eqn):
@@ -211,8 +203,7 @@ def aval_of(x):
     if isinstance(x, jax.ShapeDtypeStruct):
         return jax_core.ShapedArray(x.shape, x.dtype,
                                     weak_type=getattr(x, "weak_type", False))
-    aval = jax_core.get_aval(x)
-    return jax_core.raise_to_shaped(aval) if hasattr(jax_core, "raise_to_shaped") else aval
+    return jax.typeof(x)
 
 
 def leaf_signature(x) -> Tuple[Tuple[int, ...], str, bool]:
@@ -256,9 +247,11 @@ def keys_stable(keys: Sequence[tuple]) -> bool:
 # --------------------------------------------------------------------------------------
 
 def _block_elems(block_shape) -> int:
+    """Elements in one block: ``Blocked(block_size=k)`` dims count k,
+    squeezed dims (no ``block_size``) count 1."""
     n = 1
     for d in block_shape:
-        n *= int(d) if isinstance(d, (int, np.integer)) else 1  # Mapped dim
+        n *= int(getattr(d, "block_size", 1))
     return n
 
 
@@ -268,15 +261,18 @@ def pallas_footprint(eqn) -> Dict[str, Any]:
     Returns::
 
         {"grid": tuple, "operands": [(shape, dtype, full_bytes, block_bytes,
-                                      pinned)], "pinned_bytes": int,
-         "block_bytes": int, "prefetch_bytes": int}
+                                      pinned, smem)], "pinned_bytes": int,
+         "block_bytes": int, "prefetch_bytes": int, "smem_bytes": int}
 
     ``operands`` follows ``grid_mapping.block_mappings`` order (inputs then
     outputs); scalar-prefetch operands (PrefetchScalarGridSpec) have no block
     mapping and are accounted separately under ``prefetch_bytes``.  An operand
     is *pinned* when its block covers the full array — the whole plane is
     VMEM-resident every grid step, which is exactly what the pack's
-    ``vmem()`` budget prices.
+    ``vmem()`` budget prices.  Operands placed in SMEM (scalar-read selector
+    metadata) are flagged ``smem``: they are whole-array and read-only, but
+    live in scalar memory, so they count under ``smem_bytes`` and never as
+    pinned VMEM.
     """
     gm = eqn.params["grid_mapping"]
     grid = tuple(int(g) for g in gm.grid)
@@ -284,14 +280,14 @@ def pallas_footprint(eqn) -> Dict[str, Any]:
     # avals for [inputs..., outputs...]: invars after the scalar-prefetch
     # operands line up with the leading mappings; out_avals close the list.
     out_avals = list(eqn.params.get("out_avals") or [v.aval for v in eqn.outvars])
-    in_avals = [v.aval if hasattr(v, "aval") else jax_core.get_aval(v.val)
+    in_avals = [v.aval if hasattr(v, "aval") else jax.typeof(v.val)
                 for v in eqn.invars]
     n_prefetch = len(in_avals) + len(out_avals) - len(mappings)
     prefetch, block_ops = in_avals[:max(n_prefetch, 0)], in_avals[max(n_prefetch, 0):]
     avals = block_ops + out_avals
 
     operands = []
-    pinned_bytes = block_bytes = 0
+    pinned_bytes = block_bytes = smem_bytes = 0
     n_out = len(out_avals)
     for i, (aval, bm) in enumerate(zip(avals, mappings)):
         shape = tuple(int(d) for d in aval.shape)
@@ -299,11 +295,15 @@ def pallas_footprint(eqn) -> Dict[str, Any]:
         full = int(np.prod(shape, dtype=np.int64)) * itemsize if shape else itemsize
         bshape = tuple(bm.block_shape)
         blk = _block_elems(bshape) * itemsize
-        pinned = blk >= full
+        smem = str(getattr(bm.block_aval, "memory_space", None)) == "smem"
+        pinned = blk >= full and not smem
         operands.append({"shape": shape, "dtype": str(aval.dtype),
                          "full_bytes": full, "block_bytes": blk,
-                         "pinned": pinned,
+                         "pinned": pinned, "smem": smem,
                          "is_output": i >= len(avals) - n_out})
+        if smem:
+            smem_bytes += full
+            continue
         block_bytes += blk
         if pinned:
             pinned_bytes += full
@@ -312,24 +312,24 @@ def pallas_footprint(eqn) -> Dict[str, Any]:
         for a in prefetch)
     return {"grid": grid, "operands": operands, "pinned_bytes": pinned_bytes,
             "block_bytes": block_bytes, "prefetch_bytes": prefetch_bytes,
-            "n_prefetch": max(n_prefetch, 0)}
+            "smem_bytes": smem_bytes, "n_prefetch": max(n_prefetch, 0)}
 
 
 def pack_resident_bytes(eqn) -> int:
     """VMEM-resident bytes of the *pack* operands of one kernel launch: every
-    pinned plane (metadata comparator planes, value/code vectors) plus the
-    scalar-prefetch rows, with the activation tiles excluded.
+    pinned VMEM plane (metadata comparator planes, value/code vectors), with
+    the activation tiles excluded.
 
     The activation input and the output(s) share the kernel's tile shape (the
     output avals); with grid==1 their blocks cover the full array and would
     masquerade as pinned — any pinned operand whose shape matches an output
     aval's shape is dropped, which removes exactly x2d and the outputs and
     leaves the pack planes (metadata rows are (F, n) shapes; value/code
-    vectors are (1, M))."""
+    vectors are (1, M); the table_pack values plane is (ceil(M/128), 128))."""
     fp = pallas_footprint(eqn)
     tile_shapes = {op["shape"] for op in fp["operands"] if op["is_output"]}
-    # scalar-prefetch rows (routed fn_ids etc.) are per-call ROUTING operands
-    # living in SMEM — they are not part of the pack's VMEM residency budget
+    # scalar-prefetch rows (routed fn_ids etc.) and SMEM metadata operands
+    # live in scalar memory — they are not part of the VMEM residency budget
     total = 0
     for op in fp["operands"]:
         if op["pinned"] and op["shape"] not in tile_shapes:

@@ -442,11 +442,13 @@ def make_pack_fn(
     if use_pallas:
         from repro.kernels.table_pack_lookup import (
             table_pack_grad_pallas, table_pack_lookup_pallas)
+        from repro.parallel.sharding import map_elementwise
 
-        fwd_impl = lambda v: table_pack_lookup_pallas(
-            pack, fid, v, extrapolate=extrapolate)
-        fused_grad = lambda v: table_pack_grad_pallas(
-            pack, fid, v, extrapolate=extrapolate)
+        # under a mesh the kernel runs per shard (XLA cannot split it)
+        fwd_impl = lambda v: map_elementwise(lambda u: table_pack_lookup_pallas(
+            pack, fid, u, extrapolate=extrapolate), v)
+        fused_grad = lambda v: map_elementwise(lambda u: table_pack_grad_pallas(
+            pack, fid, u, extrapolate=extrapolate), v)
     else:
         fwd_impl = lambda v: eval_pack_ref(pack, fid, v, extrapolate=extrapolate)
         fused_grad = None
@@ -1020,7 +1022,6 @@ def eval_sharded_mesh(pack: ShardedTablePack, fn, x: jax.Array, mesh, *,
     them.  psum adds one owner value and S-1 zeros, so the result is
     bit-identical to the replicated pack AND to the off-mesh stacked sum.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     fid = _resolve(pack, fn)
@@ -1044,13 +1045,13 @@ def eval_sharded_mesh(pack: ShardedTablePack, fn, x: jax.Array, mesh, *,
         return jax.lax.psum(c, "model")
 
     rep = P(*(None,) * xf.ndim)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("model"), P("model"), P("model"), rep),
         out_specs=rep,
         # pallas_call has no shard_map replication rule; the explicit psum
         # above makes the output replicated regardless
-        check_rep=not use_pallas,
+        check_vma=not use_pallas,
     )(pack.values, pack.local_base, pack.owned, xf)
     return out.astype(dtype)
 

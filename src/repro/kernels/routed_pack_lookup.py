@@ -47,6 +47,8 @@ from repro.approx.table_pack import (PolyTablePack, QuantTablePack,
                                      poly_horner_d1, resolve_fn_ids,
                                      routed_extr_flags)
 
+from .table_lookup import resolve_interpret
+
 DEFAULT_BLOCK_COLS = 65536  # (1, 65536) f32 tile = 256 KiB in + 256 KiB out
 
 
@@ -208,8 +210,7 @@ def _routed_call(ids, n_arr, extr_arr, x2d, bounds, invd, base, segs, values,
 
 
 def _routed_prep(pack, fn_ids, x, extrapolate, block_cols, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     x2d, block, c = tile_routed_rows(x, block_cols)
     ids = resolve_fn_ids(pack, fn_ids, x2d.shape[0])
     extr = jnp.asarray(routed_extr_flags(pack, extrapolate))

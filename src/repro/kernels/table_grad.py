@@ -21,8 +21,8 @@ from jax.experimental import pallas as pl
 
 from repro.approx.jax_table import JaxTable
 
-from .table_lookup import (DEFAULT_BLOCK_ROWS, LANE, _pinned, select_params,
-                           tile_activations, untile_activations)
+from .table_lookup import (DEFAULT_BLOCK_ROWS, LANE, _pinned, resolve_interpret,
+                           select_params, tile_activations, untile_activations)
 
 
 def _table_grad_kernel(x_ref, bounds_ref, invd_ref, base_ref, segs_ref,
@@ -31,8 +31,7 @@ def _table_grad_kernel(x_ref, bounds_ref, invd_ref, base_ref, segs_ref,
     x = x_ref[...].astype(jnp.float32)
 
     p, invd, base, segs = select_params(
-        x, bounds_ref[0, :], invd_ref[0, :], base_ref[0, :], segs_ref[0, :],
-        n_intervals)
+        x, bounds_ref, invd_ref, base_ref, segs_ref, 0, n_intervals)
 
     u = (x - p) * invd
     i = jnp.clip(jnp.floor(u), 0.0, segs - 1.0)
@@ -87,8 +86,7 @@ def table_lookup_grad_pallas(
     interpret: bool | None = None,
 ):
     """Returns (y, dy/dx) with one fused selector pass."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     shape = x.shape
     x2d, block, n = tile_activations(x, lane, block_rows)
     y2d, dy2d = _call(

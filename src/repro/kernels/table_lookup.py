@@ -7,18 +7,19 @@ This is the paper's Fig. 7 pipeline re-thought for the TPU memory hierarchy
     whole kernel** — the BRAM analogue.  BlockSpecs pin them with a constant index
     map so every grid step reuses the same VMEM copy; only activation tiles stream
     HBM→VMEM.
-  * the interval selector is a *comparator plane*: ONE broadcast ``x >= bounds``
-    compare against the whole boundary row plus a sum-reduction yields the
-    sub-interval index j per element; the per-element parameters are then four
-    gathers from the VMEM metadata rows.  The paper's binary comparator tree
-    (and its LUT-count versus #intervals tradeoff, Fig. 8b) has no TPU meaning —
-    a VPU evaluates all comparators at once, and the gather replaces the old
-    n-1-deep unrolled FMA select chain (serial latency AND accumulated-rounding
-    drift) with O(1)-depth exact reads.
+  * the interval selector is a *comparator plane*: per boundary, one vector
+    ``x >= b_m`` compare selects that sub-interval's four parameters
+    (``select_params``; scalar metadata reads, which Mosaic takes from SMEM).
+    The paper's binary comparator tree (and its LUT-count versus #intervals
+    tradeoff, Fig. 8b) has no TPU meaning — a VPU evaluates a comparator over a
+    whole tile at once, and selects pick stored values exactly, so there is
+    no accumulated rounding.
   * address generation uses precomputed reciprocals ``inv_delta`` (no divide on the
     VPU hot path) and float accumulators (exact for indices < 2^24).
   * the dual-port BRAM read of (y_i, y_{i+1}) becomes one adjacent-pair gather from
-    the VMEM table; the 5-cycle fixed-point lerp becomes a single FMA.
+    the VMEM table; the 5-cycle fixed-point lerp becomes a single FMA.  This
+    per-table kernel gathers from a 1-D VMEM row, which the TPU compiler refuses;
+    the ``table_pack`` kernels read a (rows, 128) values plane instead.
 
 Tile geometry: activations are flattened to (rows, LANE) with LANE a multiple of 128
 (the VREG lane width) and rows blocked at ``block_rows`` (a multiple of 8 sublanes),
@@ -26,8 +27,7 @@ so each tile is MXU/VPU aligned.  VMEM working set per grid step:
 ``block_rows*LANE*4 (in) + same (out) + table bytes`` — checked against the VMEM
 budget by ``repro.core.bram.vmem_cost``.
 
-Validated against ``ref.table_lookup_ref`` in interpret mode (CPU container); the
-``pl.pallas_call`` + BlockSpec lowering is the TPU target.
+Validated against ``ref.table_lookup_ref`` in interpret mode on CPU.
 """
 
 from __future__ import annotations
@@ -38,28 +38,53 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.approx.jax_table import JaxTable, select_interval
+from repro.approx.jax_table import JaxTable
 
 LANE = 512  # 4 VREG lanes worth of f32; amortizes control per vector op
 DEFAULT_BLOCK_ROWS = 256  # 256x512 f32 tile = 512 KiB in + 512 KiB out
 
 
-def select_params(x, bounds_row, invd_row, base_row, segs_row, n_intervals: int):
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Whether a table kernel runs in Pallas interpret mode.
+
+    An explicit ``interpret`` wins (tests steer it).  Otherwise kernels are
+    interpreted on CPU and compiled on TPU; any other backend is an error, so
+    no accelerator ever runs the interpreter loop without saying so.
+    """
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas table kernels run compiled on TPU or "
+                       f"interpreted on CPU; backend {backend!r} is neither")
+
+
+def select_params(x, bounds_ref, invd_ref, base_ref, segs_ref, row: int,
+                  n_intervals: int):
     """Comparator plane + parameter fetch, shared by every table kernel.
 
-    The subtle part — broadcast compare + sum-reduction + clip — is the ONE
-    ``select_interval`` implementation shared with the jnp oracles, so the
-    kernel/oracle bit-identity holds by construction; this helper only adds
-    the four gathers from the VMEM-resident metadata rows.  ``bounds_row`` may
-    be right-padded (+inf in the multi-function pack plane): padding never
-    compares true and the clip pins out-of-range x into the last real
-    sub-interval.
+    A running compare-and-select over metadata row ``row`` of the four refs:
+    for each m in 1..n-1, ``x >= b_m`` overwrites the four parameters with
+    entry m.  Boundaries ascend, so the last hit is ``select_interval``'s
+    ``clip(#(x >= b_m), 0, n-1)`` and the selects return exactly the entries
+    the jnp oracles gather (bit-identical; NaN selects entry 0 in both).
+    Every read is a static scalar ``ref[row, m]``, which Mosaic lowers from
+    SMEM.  Right-padding past ``n_intervals`` (+inf in the pack plane) is
+    never read.
     """
-    j = select_interval(bounds_row, n_intervals, x)
-    p = jnp.take(bounds_row, j, axis=0, mode="clip")
-    invd = jnp.take(invd_row, j, axis=0, mode="clip")
-    base = jnp.take(base_row, j, axis=0, mode="clip")
-    segs = jnp.take(segs_row, j, axis=0, mode="clip")
+    read = lambda m: tuple(r[row, m] for r in (bounds_ref, invd_ref, base_ref,
+                                                segs_ref))
+    p, invd, base, segs = (jnp.broadcast_to(v, x.shape) for v in read(0))
+    for m in range(1, n_intervals):
+        b, d, o, s = read(m)
+        hit = x >= b
+        p = jnp.where(hit, b, p)
+        invd = jnp.where(hit, d, invd)
+        base = jnp.where(hit, o, base)
+        segs = jnp.where(hit, s, segs)
     return p, invd, base, segs
 
 
@@ -69,8 +94,7 @@ def _table_kernel(x_ref, bounds_ref, invd_ref, base_ref, segs_ref, values_ref, o
 
     # --- interval selector + parameter fetch (comparator plane + gathers) -------
     p, invd, base, segs = select_params(
-        x, bounds_ref[0, :], invd_ref[0, :], base_ref[0, :], segs_ref[0, :],
-        n_intervals)
+        x, bounds_ref, invd_ref, base_ref, segs_ref, 0, n_intervals)
 
     # --- address generation (reciprocal multiply + floor + clamp) ---------------
     u = (x - p) * invd
@@ -156,8 +180,7 @@ def table_lookup_pallas(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Evaluate the table approximator over an arbitrarily-shaped tensor."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     shape = x.shape
     x2d, block, n = tile_activations(x, lane, block_rows)
     out = _call(

@@ -1,14 +1,18 @@
 """Fused multi-function Pallas kernels over a :class:`repro.approx.TablePack`.
 
-One packed values vector + (F, n_max) metadata planes stay VMEM-resident —
-BRAM instantiation at the function-set level — and a single kernel body serves
-ANY member function: the static ``fn_id`` picks the metadata row at trace time
-(zero runtime cost; the row read lowers to a constant offset), then the shared
-comparator-plane selector (``table_lookup.select_params``) and adjacent-pair
-gather run exactly as in the per-table kernel.  Because every specialization
-shares the same operand shapes and the same pack arrays, switching functions
-costs one cached-executable lookup instead of a new table upload, and the VMEM
-working set is ONE pack instead of F separate tables.
+One packed values vector stays VMEM-resident as a (ceil(M/128), 128) plane,
+with the (F, n_max) metadata planes in SMEM — BRAM instantiation at the
+function-set level — and a single kernel body serves ANY member function: the
+static ``fn_id`` picks the metadata row at trace time (zero runtime cost; the
+scalar reads lower to constant offsets), then the shared comparator-plane
+selector (``table_lookup.select_params``) and the adjacent-pair read
+(``gather_pair``: per plane row, an in-vreg lane gather and a row select) run.
+This is the form the TPU compiler (Mosaic) accepts; the other kernels in this
+module still gather from a 1-D VMEM row and compile only in interpret mode.
+Because every specialization shares the same operand shapes and the same pack
+arrays, switching functions costs one cached-executable lookup instead of a
+new table upload, and the VMEM working set is ONE pack instead of F separate
+tables.
 
 Two entry points mirror the per-table pair:
 
@@ -23,11 +27,15 @@ Both validated bit-identical against ``repro.approx.table_pack.eval_pack_ref``
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.approx.jax_table import select_interval
 from repro.approx.table_pack import (PolyTablePack, QuantTablePack,
                                      ShardedTablePack, TablePack, poly_horner,
                                      poly_horner_d1)
@@ -36,26 +44,73 @@ from repro.core.range_reduce import (exp_edges, exp_fold, exp_reconstruct,
                                      trig_edges, trig_fold, trig_reconstruct,
                                      trig_slope_reconstruct)
 
-from .table_lookup import (DEFAULT_BLOCK_ROWS, LANE, _pinned, select_interval,
+from .table_lookup import (DEFAULT_BLOCK_ROWS, LANE, _pinned, resolve_interpret,
                            select_params, tile_activations, untile_activations)
 
 
-def _pack_kernel(x_ref, bounds_ref, invd_ref, base_ref, segs_ref, values_ref,
-                 o_ref, *, fn_id: int, n_intervals: int, extrapolate: bool):
+VREG_LANES = 128  # lanes of one vreg: the width the in-vreg gather reads
+
+
+def values_plane(values: jax.Array) -> jax.Array:
+    """The packed ``(M,)`` values as a zero-padded ``(ceil(M/128), 128)``
+    plane: one row per vreg of lanes, the layout :func:`gather_pair` reads."""
+    m = values.shape[0]
+    rows = -(-m // VREG_LANES)
+    return jnp.pad(values, (0, rows * VREG_LANES - m)).reshape(rows, VREG_LANES)
+
+
+def member_span(pack: TablePack, fid: int) -> Tuple[int, int]:
+    """Static ``(first, last)`` values index member ``fid`` reads: every
+    adjacent pair ``(a, a + 1)`` of its sub-intervals lies inside."""
+    n = pack.n_intervals[fid]
+    base = np.asarray(pack.base)[fid]
+    segs = np.asarray(pack.seg_count)[fid]
+    return int(base[0]), int(base[n - 1] + segs[n - 1])
+
+
+def gather_pair(plane_ref, a, span: Tuple[int, int]):
+    """Adjacent-pair BRAM read: ``(values[a], values[a + 1])`` for an int32
+    address tile ``a``, addresses clamped into the member's ``span`` (which
+    every finite or infinite input's pair lies in).
+
+    Mosaic gathers within one vreg only, so the tile is read 128 lanes at a
+    time: each plane row the span covers is broadcast over the chunk and read
+    with a lane gather (``take_along_axis``, lowered to an in-vreg dynamic
+    gather); the row select keeps the read whose address lies in that row.
+    Pure selection, so the pair is bit-identical to a 1-D gather from the
+    values vector.
+    """
+    lo, hi = span
+    pairs = []
+    for c in range(0, a.shape[1], VREG_LANES):
+        chunk = a[:, c:c + VREG_LANES]
+        addrs = (jnp.clip(chunk, lo, hi), jnp.clip(chunk + 1, lo, hi))
+        rows = [addr >> 7 for addr in addrs]  # VREG_LANES == 2**7
+        lanes = [addr & (VREG_LANES - 1) for addr in addrs]
+        ys = [jnp.zeros(chunk.shape, plane_ref.dtype) for _ in addrs]
+        for r in range(lo // VREG_LANES, hi // VREG_LANES + 1):
+            vals = jnp.broadcast_to(plane_ref[r:r + 1, :], chunk.shape)
+            for k in range(2):
+                got = jnp.take_along_axis(vals, lanes[k], axis=1,
+                                          mode="promise_in_bounds")
+                ys[k] = jnp.where(rows[k] == r, got, ys[k])
+        pairs.append(ys)
+    return [jnp.concatenate([p[k] for p in pairs], axis=1) for k in range(2)]
+
+
+def _pack_kernel(x_ref, bounds_ref, invd_ref, base_ref, segs_ref, plane_ref,
+                 o_ref, *, fn_id: int, n_intervals: int,
+                 span: Tuple[int, int], extrapolate: bool):
     x = x_ref[...].astype(jnp.float32)
 
     # static row pick: the ONE pack serves any function; fn_id costs nothing
     p, invd, base, segs = select_params(
-        x, bounds_ref[fn_id, :], invd_ref[fn_id, :], base_ref[fn_id, :],
-        segs_ref[fn_id, :], n_intervals)
+        x, bounds_ref, invd_ref, base_ref, segs_ref, fn_id, n_intervals)
 
     u = (x - p) * invd
     i = jnp.clip(jnp.floor(u), 0.0, segs - 1.0)
     a = (base + i).astype(jnp.int32)  # base is GLOBAL: offset baked in at pack time
-
-    values = values_ref[0, :]
-    y0 = jnp.take(values, a, axis=0, mode="clip")
-    y1 = jnp.take(values, a + 1, axis=0, mode="clip")
+    y0, y1 = gather_pair(plane_ref, a, span)
 
     t = u - i
     if not extrapolate:
@@ -64,20 +119,18 @@ def _pack_kernel(x_ref, bounds_ref, invd_ref, base_ref, segs_ref, values_ref,
 
 
 def _pack_grad_kernel(x_ref, bounds_ref, invd_ref, base_ref, segs_ref,
-                      values_ref, y_ref, dy_ref, *, fn_id: int,
-                      n_intervals: int, extrapolate: bool):
+                      plane_ref, y_ref, dy_ref, *, fn_id: int,
+                      n_intervals: int, span: Tuple[int, int],
+                      extrapolate: bool):
     x = x_ref[...].astype(jnp.float32)
 
     p, invd, base, segs = select_params(
-        x, bounds_ref[fn_id, :], invd_ref[fn_id, :], base_ref[fn_id, :],
-        segs_ref[fn_id, :], n_intervals)
+        x, bounds_ref, invd_ref, base_ref, segs_ref, fn_id, n_intervals)
 
     u = (x - p) * invd
     i = jnp.clip(jnp.floor(u), 0.0, segs - 1.0)
     a = (base + i).astype(jnp.int32)
-    values = values_ref[0, :]
-    y0 = jnp.take(values, a, axis=0, mode="clip")
-    y1 = jnp.take(values, a + 1, axis=0, mode="clip")
+    y0, y1 = gather_pair(plane_ref, a, span)
 
     t = u - i
     slope = (y1 - y0) * invd
@@ -97,52 +150,57 @@ def _pack_specs(x2d, pack_arrays, block_rows):
     return (rows // block_rows,), in_specs
 
 
+def _plane_call(kernel, n_out, x2d, bounds, invd, base, segs, values, *,
+                block_rows, interpret, **static):
+    """Launch a ``table_pack`` kernel: selector metadata in SMEM (scalar
+    reads), the values plane pinned in VMEM, activations streamed in
+    ``(block_rows, lane)`` tiles."""
+    rows, lane = x2d.shape
+    if lane % VREG_LANES:
+        raise ValueError(f"table_pack kernels tile {VREG_LANES}-lane chunks; "
+                         f"lane={lane} is not a multiple")
+    plane = values_plane(values)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tile = pl.BlockSpec((block_rows, lane), lambda i: (i, 0))
+    out = jax.ShapeDtypeStruct(x2d.shape, x2d.dtype)
+    return pl.pallas_call(
+        functools.partial(kernel, **static),
+        grid=(rows // block_rows,),
+        in_specs=[tile, smem, smem, smem, smem, _pinned(plane.shape)],
+        out_specs=tile if n_out == 1 else [tile] * n_out,
+        out_shape=out if n_out == 1 else [out] * n_out,
+        interpret=interpret,
+    )(x2d, bounds, invd, base, segs, plane)
+
+
 @functools.partial(
     jax.jit, static_argnames=("block_rows", "interpret", "fn_id", "n_intervals",
-                              "extrapolate"))
+                              "span", "extrapolate"))
 def _call(x2d, bounds, invd, base, segs, values, *, block_rows, interpret,
-          fn_id, n_intervals, extrapolate):
-    grid, in_specs = _pack_specs(x2d, (bounds, invd, base, segs, values),
-                                 block_rows)
-    kernel = functools.partial(_pack_kernel, fn_id=fn_id,
-                               n_intervals=n_intervals, extrapolate=extrapolate)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_rows, x2d.shape[1]), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
-        interpret=interpret,
-    )(x2d, bounds, invd, base, segs, values)
+          fn_id, n_intervals, span, extrapolate):
+    return _plane_call(_pack_kernel, 1, x2d, bounds, invd, base, segs, values,
+                       block_rows=block_rows, interpret=interpret, fn_id=fn_id,
+                       n_intervals=n_intervals, span=span,
+                       extrapolate=extrapolate)
 
 
 @functools.partial(
     jax.jit, static_argnames=("block_rows", "interpret", "fn_id", "n_intervals",
-                              "extrapolate"))
+                              "span", "extrapolate"))
 def _call_grad(x2d, bounds, invd, base, segs, values, *, block_rows, interpret,
-               fn_id, n_intervals, extrapolate):
-    grid, in_specs = _pack_specs(x2d, (bounds, invd, base, segs, values),
-                                 block_rows)
-    kernel = functools.partial(_pack_grad_kernel, fn_id=fn_id,
-                               n_intervals=n_intervals, extrapolate=extrapolate)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((block_rows, x2d.shape[1]), lambda i: (i, 0))] * 2,
-        out_shape=[jax.ShapeDtypeStruct(x2d.shape, x2d.dtype)] * 2,
-        interpret=interpret,
-    )(x2d, bounds, invd, base, segs, values)
+               fn_id, n_intervals, span, extrapolate):
+    return _plane_call(_pack_grad_kernel, 2, x2d, bounds, invd, base, segs,
+                       values, block_rows=block_rows, interpret=interpret,
+                       fn_id=fn_id, n_intervals=n_intervals, span=span,
+                       extrapolate=extrapolate)
 
 
 def _prep(pack: TablePack, fn, x, lane, block_rows, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     # member_id validates ints too: an out-of-range fn_id raises a KeyError
     # naming the pack members instead of an opaque tuple IndexError below
     fid = pack.member_id(fn)
     x2d, block, n = tile_activations(x, lane, block_rows)
-    return fid, x2d, block, n, interpret
+    return fid, x2d, block, n, resolve_interpret(interpret)
 
 
 def table_pack_lookup_pallas(
@@ -160,9 +218,10 @@ def table_pack_lookup_pallas(
                                           interpret)
     out = _call(
         x2d, pack.boundaries, pack.inv_delta, pack.base, pack.seg_count,
-        pack.values.reshape(1, -1),
+        pack.values,
         block_rows=block, interpret=interpret, fn_id=fid,
-        n_intervals=pack.n_intervals[fid], extrapolate=extrapolate,
+        n_intervals=pack.n_intervals[fid], span=member_span(pack, fid),
+        extrapolate=extrapolate,
     )
     return untile_activations(out, n, x.shape)
 
@@ -192,8 +251,7 @@ def _tableflash_kernel(x_ref, bounds_ref, invd_ref, base_ref, segs_ref,
     x = jnp.maximum(x_raw, lo)  # address saturation only
 
     p, invd, base, segs = select_params(
-        x, bounds_ref[fn_id, :], invd_ref[fn_id, :], base_ref[fn_id, :],
-        segs_ref[fn_id, :], n_intervals)
+        x, bounds_ref, invd_ref, base_ref, segs_ref, fn_id, n_intervals)
 
     u = (x - p) * invd
     i = jnp.clip(jnp.floor(u), 0.0, segs - 1.0)
@@ -443,9 +501,10 @@ def table_pack_grad_pallas(
                                           interpret)
     y2d, dy2d = _call_grad(
         x2d, pack.boundaries, pack.inv_delta, pack.base, pack.seg_count,
-        pack.values.reshape(1, -1),
+        pack.values,
         block_rows=block, interpret=interpret, fn_id=fid,
-        n_intervals=pack.n_intervals[fid], extrapolate=extrapolate,
+        n_intervals=pack.n_intervals[fid], span=member_span(pack, fid),
+        extrapolate=extrapolate,
     )
     return (untile_activations(y2d, n, x.shape),
             untile_activations(dy2d, n, x.shape))
@@ -789,8 +848,7 @@ def sharded_shard_contrib_pallas(
     the (F, n_max) planes of the CALLING shard and ``values_s`` its (m_max,)
     slice.  The caller combines contributions (psum on mesh, sum off-mesh).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     x2d, block, n = tile_activations(x, lane, block_rows)
     out = _sharded_call(
         x2d, boundaries, inv_delta, seg_count, local_base, owned,
@@ -859,8 +917,7 @@ def sharded_pack_grad_pallas(
     """Returns (y, dy/dx) from the sharded pack — one FUSED selector pass per
     shard (S launches total, like the replicated ``table_pack_grad_pallas``'s
     single fused launch)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     fid = pack.member_id(fn)
     x2d, block, n = tile_activations(x, lane, block_rows)
     y2d = dy2d = None
@@ -898,8 +955,7 @@ def _folded_core_lookup(x, bounds_ref, invd_ref, base_ref, segs_ref, values,
     ``extrapolate=False`` (the cores never extrapolate — the fold guarantees
     in-domain arguments up to the guard band, which clamps)."""
     p, invd, base, segs = select_params(
-        x, bounds_ref[fid, :], invd_ref[fid, :], base_ref[fid, :],
-        segs_ref[fid, :], n_intervals)
+        x, bounds_ref, invd_ref, base_ref, segs_ref, fid, n_intervals)
     u = (x - p) * invd
     i = jnp.clip(jnp.floor(u), 0.0, segs - 1.0)
     a = (base + i).astype(jnp.int32)
@@ -913,8 +969,7 @@ def _folded_core_slope(x, bounds_ref, invd_ref, base_ref, segs_ref, values,
                        fid: int, n_intervals: int):
     """Chord slope of one core member — mirrors ``eval_pack_slope``."""
     p, invd, base, segs = select_params(
-        x, bounds_ref[fid, :], invd_ref[fid, :], base_ref[fid, :],
-        segs_ref[fid, :], n_intervals)
+        x, bounds_ref, invd_ref, base_ref, segs_ref, fid, n_intervals)
     i = jnp.clip(jnp.floor((x - p) * invd), 0.0, segs - 1.0)
     a = (base + i).astype(jnp.int32)
     y0 = jnp.take(values, a, axis=0, mode="clip")
@@ -1020,8 +1075,7 @@ def _folded_call_grad(x2d, bounds, invd, base, segs, values, *, block_rows,
 def _folded_prep(pack: TablePack, name: str, x, lane, block_rows, interpret):
     from repro.approx.range_fold import FOLDABLE
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if name not in FOLDABLE:
         raise KeyError(f"folded kernel serves {sorted(FOLDABLE)}, got {name!r}; "
                        f"use table_pack_lookup_pallas for plain members")

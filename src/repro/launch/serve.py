@@ -26,6 +26,7 @@ import numpy as np
 
 from repro import obs
 from repro.approx import TABLE_MODES
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model, get_config
 from repro.serving.engine import (ContinuousEngine, DecodeEngine, Request,
                                   serve_static)
@@ -83,6 +84,7 @@ def main():
                          "(out-of-domain clamps, quant saturation, routed "
                          "dispatch) and print the metric summary")
     args = ap.parse_args()
+    use_compile_cache()
 
     # host-side spans are always on for the launcher (they never touch the
     # device computation); device telemetry only with --obs, and only then is

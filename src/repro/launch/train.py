@@ -18,6 +18,7 @@ import jax
 
 from repro import obs
 from repro.approx import TABLE_MODES
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import ShapeSpec, build_model, get_config
 from repro.optim import adamw
 from repro.train.loop import TrainConfig, run
@@ -82,6 +83,7 @@ def main():
                     help="enable device-side approximation telemetry and "
                          "print the metric summary")
     args = ap.parse_args()
+    use_compile_cache()
 
     obs.configure(enabled=True, device_telemetry=args.obs,
                   trace_path=args.trace)

@@ -17,6 +17,7 @@ from contextlib import contextmanager
 from typing import Any, Dict, Optional
 
 import jax
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 _ctx = threading.local()
@@ -58,6 +59,34 @@ def logical_to_spec(*logical) -> P:
     b = getattr(_ctx, "binding", None)
     rules = b[1] if b else {}
     return P(*(rules.get(l) if l is not None else None for l in logical))
+
+
+def map_elementwise(f, x: jax.Array):
+    """``f(x)`` for an elementwise ``f``, run per shard under the active mesh.
+
+    XLA cannot partition a Pallas (Mosaic) kernel, so under a binding the
+    call is wrapped in ``shard_map``: the last dim splits over 'model' and the
+    first over the batch axes where they divide, any other dim stays whole.
+    Being elementwise, ``f`` gives the same values on any split.  Without a
+    binding this is ``f(x)``.  ``f`` may return a tuple of x-shaped arrays.
+    """
+    b = getattr(_ctx, "binding", None)
+    if not b or b[0] is None:
+        return f(x)
+    mesh, rules = b
+    sizes = dict(mesh.shape)
+    width = lambda ax: int(np.prod([sizes[a] for a in
+                                    (ax if isinstance(ax, tuple) else (ax,))]))
+    spec = [None] * x.ndim
+    model, batch = rules.get("model"), rules.get("batch")
+    if x.ndim and model is not None and x.shape[-1] % width(model) == 0:
+        spec[-1] = model
+    if x.ndim > 1 and batch is not None and x.shape[0] % width(batch) == 0:
+        spec[0] = batch
+    spec = P(*spec)
+    # pallas_call has no shard_map varying-axes rule: skip that check
+    return jax.shard_map(f, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)(x)
 
 
 def shard_activation(x: jax.Array, *logical) -> jax.Array:

@@ -189,3 +189,44 @@ class TestFusedGradKernel:
         g_ref = jax.vmap(jax.grad(ApproxConfig(mode="table_ref",
                                                e_a=1e-4).unary("silu")))(x)
         np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=1e-5)
+
+
+class TestBackendDecisions:
+    """Interpret mode and the compile cache are each decided in one place."""
+
+    @pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False)])
+    def test_interpret_follows_backend(self, backend, want, monkeypatch):
+        from repro.kernels.table_lookup import resolve_interpret
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert resolve_interpret(None) is want
+        # an explicit choice always wins (tests steer it)
+        assert resolve_interpret(not want) is (not want)
+
+    def test_other_backend_raises(self, monkeypatch):
+        from repro.kernels.table_lookup import resolve_interpret
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="gpu"):
+            resolve_interpret(None)
+        assert resolve_interpret(True) is True
+
+    def test_compile_cache_placement(self, monkeypatch, tmp_path):
+        import os
+
+        from repro.launch import compile_cache
+
+        updates = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *a: updates.append(a))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.use_compile_cache() == str(tmp_path)
+        assert updates == []  # JAX reads the variable itself
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.use_compile_cache()
+        assert updates == [("jax_compilation_cache_dir", path)]
+        root = os.path.dirname(path)
+        assert os.path.basename(path) == ".jax_cache"
+        assert os.path.isfile(os.path.join(root, "chip_smoke.py"))
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()

@@ -336,7 +336,8 @@ def test_exp_group_law_through_table(x):
 
 
 @settings(deadline=None)
-@given(x=st.floats(-0.78, 0.78, allow_subnormal=False, width=32))
+@given(x=st.floats(float(np.float32(-0.78)), float(np.float32(0.78)),
+                 allow_subnormal=False, width=32))
 def test_folded_trig_bit_parity_on_core(x):
     """|x| < pi/4: folded sin/cos == the raw core member lookup, BITWISE
     (the fold is an identity and the reconstruction is transparent)."""
@@ -354,7 +355,8 @@ def test_folded_trig_bit_parity_on_core(x):
 
 
 @settings(deadline=None)
-@given(x=st.floats(-0.34, 0.34, allow_subnormal=False, width=32))
+@given(x=st.floats(float(np.float32(-0.34)), float(np.float32(0.34)),
+                 allow_subnormal=False, width=32))
 def test_folded_exp_bit_parity_on_core(x):
     """|x| < ln2/2: folded exp == the raw exp_core lookup bitwise (k = 0)."""
     import jax.numpy as jnp
