@@ -14,12 +14,13 @@ Phases (one chip):
 * kernel  — every member of the ``table_pack`` pack through the compiled
   ``table_pack_lookup_pallas`` / ``table_pack_grad_pallas`` on a dense grid
   over its domain, held to the pack's error budget Ea against the f64
-  function.
+  function and to bit-identity with ``eval_pack_ref`` / ``eval_pack_slope``.
 * serve   — stablelm-3b (d_model 2560, 32 MHA heads, d_ff 6912, vocab 50304)
   in ``table_pack`` mode, random weights from ``--seed``, served through
   ``ContinuousEngine.serve`` as ``repro.launch.serve`` does.  Depth is cut to
-  what ``memory_analysis()`` of the prefill and decode executables shows fits
-  the chip with headroom; the decode tick must contain the Pallas kernel
+  ``SERVE_LAYERS``; ``memory_analysis()`` of the prefill and decode
+  executables must show each fits the chip with headroom.  The decode tick
+  must contain the Pallas kernel
   (``tpu_custom_call``) and its logits must be finite.
 * parity  — the same prompts and weights through ``table_pack_ref`` (the jnp
   oracle): first-step logit gap and greedy-token agreement are printed;
@@ -42,6 +43,8 @@ import time
 import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src"))
+# libtpu otherwise writes its logs to a fixed /tmp/tpu_logs, outside the checkout
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +61,11 @@ from repro.serving.engine import ContinuousEngine, Request
 ARCH = "stablelm-3b"
 BATCH, CACHE_LEN, MAX_NEW, N_REQUESTS = 4, 256, 16, 8
 HEADROOM = 0.8  # share of the chip's bytes_limit one executable may take
+# The deepest cut whose prefill and decode executables fit HEADROOM x the
+# v5e bytes_limit (16.909 GB) by memory_analysis(): 13.470 GB each at 25
+# layers, about 0.52 GB more per layer.  The full 32 do not fit: the bf16
+# weight copy is hoisted out of the layer scan (ROADMAP).
+SERVE_LAYERS = 25
 GRID = 1 << 16  # kernel-phase points per member
 FOUR_CHIP_LAYERS = 8
 GB = 1e9
@@ -98,13 +106,9 @@ def device_phase(want_count: int):
 # ------------------------------- kernel --------------------------------------
 
 
-def ea_bound(e_a: float, want: np.ndarray) -> float:
-    """Ea plus the f32 gather/FMA rounding the conformance suite allows on
-    top of the f64 design bound."""
-    return e_a * 1.02 + 1e-5 * max(1.0, float(np.max(np.abs(want))))
-
-
 def kernel_phase(approx) -> None:
+    """Each member through the compiled kernels: within Ea of the f64
+    function, and bit-identical to the jnp oracle (value and slope)."""
     pack = approx.pack()
     for name in pack.names:
         lo, hi = (float(v) for v in member_domain(pack, name))
@@ -116,20 +120,22 @@ def kernel_phase(approx) -> None:
                   table_pack_grad_pallas(pack, name, x, interpret=False))
         ref = np.asarray(jax.jit(lambda v: eval_pack_ref(pack, name, v))(x))
         ref_dy = np.asarray(jax.jit(lambda v: eval_pack_slope(pack, name, v))(x))
-        err = np.abs(y.astype(np.float64) - want)
-        err_g = np.abs(yg.astype(np.float64) - want)
-        bound = ea_bound(approx.e_a, want)
+        err = max(np.abs(y.astype(np.float64) - want).max(),
+                  np.abs(yg.astype(np.float64) - want).max())
+        gap = max(np.abs(y - ref).max(), np.abs(yg - ref).max())
+        gap_dy = np.abs(dy - ref_dy).max()
         log("kernel", f"{name}: [{lo:g}, {hi:g}) n={GRID} worst|kernel-f|="
-                      f"{err.max():.6e} grad-kernel y {err_g.max():.6e} "
-                      f"(<= Ea={approx.e_a:g}: {max(err.max(), err_g.max()) <= approx.e_a}; "
-                      f"gate {bound:.6e}); "
-                      f"max|kernel-eval_pack_ref|={np.abs(y - ref).max():.3e} "
-                      f"max|dy-eval_pack_slope|={np.abs(dy - ref_dy).max():.3e}")
+                      f"{err:.6e} (Ea={approx.e_a:g}); "
+                      f"max|kernel-eval_pack_ref|={gap:.3e} "
+                      f"max|dy-eval_pack_slope|={gap_dy:.3e}")
         check(bool(np.isfinite(y).all() and np.isfinite(yg).all()
                    and np.isfinite(dy).all()), "kernel",
               f"{name}: non-finite kernel output")
-        check(err.max() <= bound and err_g.max() <= bound, "kernel",
-              f"{name}: error {max(err.max(), err_g.max()):.6e} over {bound:.6e}")
+        check(err <= approx.e_a, "kernel",
+              f"{name}: error {err:.6e} over Ea={approx.e_a:g}")
+        check(gap == 0 and gap_dy == 0, "kernel",
+              f"{name}: kernel differs from the jnp oracle (value {gap:.3e}, "
+              f"slope {gap_dy:.3e})")
 
 
 # ------------------------------- serve ---------------------------------------
@@ -181,49 +187,23 @@ def device_limit(device) -> int:
     return device.memory_stats()["bytes_limit"]
 
 
-def fit_depth(prefill_len: int, device, limit: int):
-    """Deepest cut (<= the published depth) whose prefill and decode
-    executables each fit ``HEADROOM * limit``.  The layers are scanned, so
-    bytes grow about linearly: a line through depths 2 and 4 gives the first
-    guess, and compiles one layer deeper or shallower settle it."""
-    full = get_config(ARCH).n_layers
-    budget = HEADROOM * limit
-    sizes = {}
-
-    def fits(depth: int) -> bool:
-        if depth not in sizes:
-            sizes[depth] = engine_sizes(depth, prefill_len, device)
-        return max(sizes[depth].values()) <= budget
-
-    fits(2), fits(4)
-    per_layer = {k: (sizes[4][k] - sizes[2][k]) / 2 for k in sizes[2]}
-    depth = min([full] + [int(2 + (budget - sizes[2][k]) // per_layer[k])
-                          for k in per_layer])
-    while depth > 0 and not fits(depth):
-        depth -= 1
-    while 0 < depth < full and fits(depth + 1):
-        depth += 1
-    if depth < 1:
-        raise SmokeFailure("[serve] FAIL: not even one layer fits the chip")
-    return depth, sizes[depth], per_layer
-
-
 def serve_phase(devs, seed: int):
     d = devs[0]
-    vocab = get_config(ARCH).vocab
+    cfg = get_config(ARCH)
+    vocab = cfg.vocab
     reqs = make_requests(vocab, seed)
     width = max(len(r.prompt) for r in reqs)
     limit = device_limit(d)
-    depth, sizes, per_layer = fit_depth(width, d, limit)
-    cfg = get_config(ARCH)
+    depth = SERVE_LAYERS
+    sizes = engine_sizes(depth, width, d)
     log("serve", f"depth {depth} of {cfg.n_layers} layers (cut "
                  f"{cfg.n_layers - depth}) at d_model {cfg.d_model} / "
                  f"{cfg.n_heads} heads / d_ff {cfg.d_ff} / vocab {vocab}; "
                  f"prefill {sizes['prefill'] / GB:.3f} GB, decode tick "
                  f"{sizes['decode_step'] / GB:.3f} GB vs {HEADROOM:g} x "
-                 f"bytes_limit {limit / GB:.3f} GB; per layer prefill "
-                 f"{per_layer['prefill'] / GB:.4f} GB, tick "
-                 f"{per_layer['decode_step'] / GB:.4f} GB")
+                 f"bytes_limit {limit / GB:.3f} GB")
+    check(max(sizes.values()) <= HEADROOM * limit, "serve",
+          f"{depth} layers do not fit {HEADROOM:g} x bytes_limit")
 
     model = build_model(stablelm("table_pack", depth))
     params = model.init(jax.random.key(seed))

@@ -69,6 +69,11 @@ def map_elementwise(f, x: jax.Array):
     first over the batch axes where they divide, any other dim stays whole.
     Being elementwise, ``f`` gives the same values on any split.  Without a
     binding this is ``f(x)``.  ``f`` may return a tuple of x-shaped arrays.
+
+    The split is guessed from the shape, not read from the activation's
+    logical axes.  It matches (batch, ..., ff|vocab|heads) activations — the
+    MLP/GLU hidden, softcapped logits, Mamba2's dt; elsewhere (MoE expert
+    blocks, xLSTM's (B, H, S) gates) XLA reshards around the call.
     """
     b = getattr(_ctx, "binding", None)
     if not b or b[0] is None:

@@ -7,6 +7,8 @@ two each.  The topology is described inside a fixture so that only the
 worker running this file loads the TPU compiler.
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -22,6 +24,8 @@ from repro.serving.engine import ContinuousEngine
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
+    # libtpu otherwise writes its logs to a fixed /tmp/tpu_logs
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
