@@ -1,0 +1,13 @@
+"""Useful model FLOPs of the window's served requests per window second, as
+a percent of the chips' bf16 peak.  Useful work counts logical heads,
+unpadded prompt tokens and served tokens (flops.request_flops): padding,
+padded heads and wasted slot rows do not count."""
+
+import flops
+
+
+def read(run):
+    work = sum(flops.request_flops(run["config"], len(r["prompt"]), len(r["out"]))
+               for r in run["requests"] if r["out"] is not None)
+    peak = run["peak"]["bf16_flops"] * run["chips"]
+    return 100.0 * work / run["window_s"] / peak
