@@ -8,7 +8,7 @@
 ``--scheduler static`` keeps the fixed-group baseline.
 
 ScopeKit (docs/observability.md): ``--trace PATH`` writes a Perfetto-loadable
-Chrome trace of the run (request lifecycles, refill/decode spans, jit-compile
+Chrome trace of the run (request lifecycles, admission/decode phase spans, jit-compile
 events) with the engine's metric summary embedded; ``--obs`` additionally
 enables device-side approximation telemetry and prints the metric summary.
 The launcher always records host-side spans, so throughput is reported both

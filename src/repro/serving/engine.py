@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional, Sequence
 
@@ -106,34 +106,60 @@ class _EngineBase:
                 for name, fn in self._executables.items()}
 
 
+# The ContinuousEngine's phase spans: name -> the span it nests in.  An
+# admission round and a no-feedback decode span each split into the host's
+# own work and the two places it waits on the device (WAIT_SPANS).
+ENGINE_SPANS = {
+    "engine.serve": None,  # one serve() call
+    "engine.admit": "engine.serve",  # one admission round
+    "engine.prefill": "engine.admit",  # row build, H2D, prefill dispatch
+    "engine.scatter": "engine.admit",  # dispatch of the eager slot scatter
+    "engine.prefill_wait": "engine.admit",  # host waits on prefill logits
+    "engine.first_tokens": "engine.admit",  # sampling + first emits
+    "engine.decode": "engine.serve",  # one span of k fused ticks
+    "engine.dispatch": "engine.decode",  # the k tick dispatches
+    "engine.settle": "engine.decode",  # host waits on the k tokens
+    "engine.emit": "engine.decode",  # per-token bookkeeping + callbacks
+}
+WAIT_SPANS = ("engine.prefill_wait", "engine.settle")
+
+
 @contextmanager
 def _phase_span(engine, tracer, name: str, cat: str = "serve", fn=None,
                 **args):
-    """B/E span around one engine phase, recorded only when the caller already
-    checked ``obs.enabled()``.  The body may set two keys on the yielded
-    state dict: ``sync`` (a jax value to ``block_until_ready`` before the E
-    event, so durations measure work rather than dispatch) and ``end_args``
-    (extra fields for the E event).  If ``fn``'s jit cache grew during the
-    span, the span is flagged ``compiled=True``, a ``jit.compile`` instant is
-    emitted, and the duration feeds ``engine.compile_time_s`` — the number
-    the CLIs subtract to report steady-state throughput.  After the span,
-    ``st["dur_s"]`` holds the measured duration."""
-    before = _jit_cache_size(fn) if fn is not None else -1
-    tracer.begin(name, cat, **args)
-    t0 = time.perf_counter()
-    st: dict = {}
-    try:
-        yield st
-        if st.get("sync") is not None:
-            jax.block_until_ready(st["sync"])
-    finally:
-        st["dur_s"] = time.perf_counter() - t0
-        end_args = dict(st.get("end_args") or {})
-        if fn is not None and _jit_cache_size(fn) > before:
-            end_args["compiled"] = True
-            engine.compile_time_s += st["dur_s"]
-            tracer.instant("jit.compile", "jit", phase=name)
-        tracer.end(name, cat, **end_args)
+    """One engine phase, written to two sinks and never synced: the span
+    ends when the host leaves the phase, so a phase that dispatches ends at
+    dispatch, and time the host spends waiting on the device shows in the
+    phase that waits.
+
+    Always a ``jax.profiler.TraceAnnotation`` (``args`` as its stats): the
+    profiler's host plane, on the device trace's clock, obs on or off.  With
+    a ScopeKit ``tracer`` (the caller passes one only when
+    ``obs.enabled()``) also B/E on its engine track; the body may then set
+    ``end_args`` (extra fields for the E event) on the yielded dict.  If
+    ``fn``'s jit cache grew during the span, the E event is flagged
+    ``compiled=True``, a ``jit.compile`` instant is emitted, and the duration
+    feeds ``engine.compile_time_s`` -- the number the CLIs subtract to report
+    steady-state throughput.  After the span, ``st["dur_s"]`` holds its
+    duration."""
+    with jax.profiler.TraceAnnotation(name, **args):
+        if tracer is None:
+            yield {}
+            return
+        before = _jit_cache_size(fn) if fn is not None else -1
+        tracer.begin(name, cat, **args)
+        t0 = time.perf_counter()
+        st: dict = {}
+        try:
+            yield st
+        finally:
+            st["dur_s"] = time.perf_counter() - t0
+            end_args = dict(st.get("end_args") or {})
+            if fn is not None and _jit_cache_size(fn) > before:
+                end_args["compiled"] = True
+                engine.compile_time_s += st["dur_s"]
+                tracer.instant("jit.compile", "jit", phase=name)
+            tracer.end(name, cat, **end_args)
 
 
 def _place_engine_packs(model, mesh) -> None:
@@ -206,11 +232,11 @@ class DecodeEngine(_EngineBase):
         batch = {"tokens": jnp.asarray(prompts, jnp.int32)}
         if extra_inputs:
             batch.update({k: jnp.asarray(v) for k, v in extra_inputs.items()})
-        cm = (_phase_span(self, tracer, "static.prefill", fn=self._prefill,
-                          batch=B, prompt_len=S) if rec else nullcontext({}))
-        with cm as st:
+        with _phase_span(self, tracer, "static.prefill", fn=self._prefill,
+                         batch=B, prompt_len=S):
             logits, cache = self._prefill(self.params, batch, cache)
-            st["sync"] = logits
+            if rec:  # the span then measures work, not dispatch
+                jax.block_until_ready(logits)
         out = [self._sample(logits)]
         self.batch_steps += 1
         if rec:
@@ -222,9 +248,7 @@ class DecodeEngine(_EngineBase):
         if has_eos:
             done = done | ((eos >= 0) & (np.asarray(out[0]) == eos))
         steps = 1  # the prefill logits already yielded one token
-        cm = (_phase_span(self, tracer, "static.decode", fn=self._step)
-              if rec else nullcontext({}))
-        with cm as st:
+        with _phase_span(self, tracer, "static.decode", fn=self._step) as st:
             for i in range(horizon - 1):
                 if done.all():
                     break
@@ -240,8 +264,9 @@ class DecodeEngine(_EngineBase):
                 done = done | (budget <= steps)
                 if has_eos:
                     done = done | ((eos >= 0) & (np.asarray(nxt) == eos))
-            st["sync"] = out[-1]
-            st["end_args"] = {"steps": steps - 1}
+            if rec:
+                jax.block_until_ready(out[-1])
+                st["end_args"] = {"steps": steps - 1}
         if rec and steps > 1:
             # decode ticks are uniform in the static loop, so the amortized
             # per-step interval stands in for each inter-token latency
@@ -412,8 +437,16 @@ class ContinuousEngine(_EngineBase):
     # ------------------------------- serve -----------------------------------
 
     def serve(self, requests: List[Request],
-              on_result: Optional[Callable[[int, Result], None]] = None
+              on_result: Optional[Callable[[int, Result], None]] = None,
+              on_token: Optional[Callable[[int, int, float], None]] = None
               ) -> List[Result]:
+        """Serve the whole queue; returns one ``Result`` per request.
+
+        ``on_result(req_idx, result)`` fires when a request finishes.
+        ``on_token(req_idx, n, t)`` fires once per served token, in order
+        (``n == 0`` at a request's first token), with ``t`` the
+        ``time.perf_counter()`` at which token ``n`` settled on the host --
+        the streaming server's hook, costing one ``if`` when ``None``."""
         if not requests:
             return []
         B = self.B
@@ -429,20 +462,23 @@ class ContinuousEngine(_EngineBase):
             self._axes = cache_batch_axes(self.model, self.cache_len)
         if self._fresh is None:
             self._fresh = self.model.init_cache(B, self.cache_len)
+        tracer = obs.get_tracer() if obs.enabled() else None
+        if tracer is not None:
+            tracer.set_thread_name(MAIN_TID, "engine")
+        with _phase_span(self, tracer, "engine.serve", requests=len(requests),
+                         batch=B, prefill_len=S0):
+            return self._serve_queue(requests, S0, tracer, on_result,
+                                     on_token)
 
+    def _serve_queue(self, requests, S0, tracer, on_result, on_token):
         # Per-request lifecycle spans live on one trace track per slot
         # (tid = SLOT_TID0 + slot): a slot serves one request at a time, so
         # every track's B/E events are balanced and non-overlapping.  All
         # requests enqueue at serve() entry, so queue_wait_s is admission
         # time minus t0 and ttft_s additionally includes the prefill.
-        rec = obs.enabled()
-        tracer = obs.get_tracer() if rec else None
+        B = self.B
+        rec = tracer is not None
         t0 = time.perf_counter()
-        if rec:
-            tracer.set_thread_name(MAIN_TID, "engine")
-            tracer.instant("serve.begin", "serve", requests=len(requests),
-                           batch=B, prefill_len=S0)
-
         results: List[Optional[Result]] = [None] * len(requests)
         pending = deque(enumerate(requests))
         live: List[Optional[_Slot]] = [None] * B
@@ -453,8 +489,10 @@ class ContinuousEngine(_EngineBase):
         tok_dev = None  # (B, 1) int32 device-resident fed-back token
         pos_dev = None  # (B,) int32 device-resident clocks
 
-        def emit(j: int, tok: int) -> None:
+        def emit(j: int, tok: int, t: float) -> None:
             s = live[j]
+            if on_token is not None:
+                on_token(s.req_idx, len(s.emitted), t)
             s.emitted.append(tok)
             if (s.eos_id >= 0 and tok == s.eos_id) or \
                     len(s.emitted) >= s.budget:
@@ -468,75 +506,85 @@ class ContinuousEngine(_EngineBase):
                                tokens=len(s.emitted))
                 live[j] = None
 
+        def build_rows():
+            """The next admission's prompt rows and its (slot, req_idx,
+            request) triples; zero-budget requests finish on the way."""
+            rows = np.full((B, S0), self.pad_id, np.int32)
+            take = []
+            for j in [j for j in range(B) if live[j] is None]:
+                i, r = None, None
+                while pending:  # zero-budget requests never take a slot
+                    i, r = pending.popleft()
+                    if r.max_new_tokens >= 1:
+                        break
+                    res = Result(tokens=np.zeros((0,), np.int64),
+                                 prompt_len=len(r.prompt), steps=0)
+                    results[i] = res
+                    if on_result is not None:
+                        on_result(i, res)
+                    i, r = None, None
+                if r is None:
+                    break
+                rows[j, S0 - len(r.prompt):] = r.prompt
+                take.append((j, i, r))
+            return rows, take
+
         while True:
             # admission: one fixed-shape prefill serves every free slot
             # (budget-1 / instant-EOS admissions free their slot immediately,
             # so keep refilling until slots or queue run dry)
             admitted = False
             while pending and any(s is None for s in live):
-                free = [j for j in range(B) if live[j] is None]
-                rows = np.full((B, S0), self.pad_id, np.int32)
-                take = []
-                for j in free:
-                    i, r = None, None
-                    while pending:  # zero-budget requests never take a slot
-                        i, r = pending.popleft()
-                        if r.max_new_tokens >= 1:
-                            break
-                        res = Result(tokens=np.zeros((0,), np.int64),
-                                     prompt_len=len(r.prompt), steps=0)
-                        results[i] = res
-                        if on_result is not None:
-                            on_result(i, res)
-                        i, r = None, None
-                    if r is None:
+                with _phase_span(self, tracer, "engine.admit",
+                                 fn=self._prefill) as adm:
+                    with _phase_span(self, tracer, "engine.prefill"):
+                        rows, take = build_rows()
+                        t_admit = time.perf_counter()
+                        if take:
+                            logits, rcache = self._prefill(
+                                self.params, {"tokens": jnp.asarray(rows)},
+                                self._fresh)
+                    if not take:
                         break
-                    rows[j, S0 - len(r.prompt):] = r.prompt
-                    take.append((j, i, r))
-                if not take:
-                    break
-                t_admit = time.perf_counter()
-                cm = (_phase_span(self, tracer, "refill.prefill",
-                                  fn=self._prefill, admitted=len(take))
-                      if rec else nullcontext({}))
-                with cm as st:
-                    logits, rcache = self._prefill(
-                        self.params, {"tokens": jnp.asarray(rows)},
-                        self._fresh)
-                    st["sync"] = logits
-                self.prefills += 1
-                self.batch_steps += 1
-                self.wasted_slot_steps += B - len(take)
-                self.refills += sum(used[j] for j, _, _ in take)
-                for j, _, _ in take:
-                    used[j] = True
-                cm = (_phase_span(self, tracer, "refill.scatter",
-                                  slots=len(take)) if rec else nullcontext({}))
-                with cm as st:
-                    cache = scatter_cache_slots(cache, rcache,
-                                                [j for j, _, _ in take],
-                                                self._axes)
-                    st["sync"] = cache
-                lg = np.asarray(logits)
-                for j, i, r in take:
-                    live[j] = _Slot(req_idx=i, prompt_len=len(r.prompt),
-                                    budget=r.max_new_tokens, eos_id=r.eos_id)
-                    pos[j] = S0
                     if rec:
-                        tracer.set_thread_name(SLOT_TID0 + j, f"slot {j}")
-                        tracer.begin("request", "request", SLOT_TID0 + j,
-                                     req_idx=i, prompt_len=len(r.prompt),
-                                     budget=r.max_new_tokens)
-                        self.metrics.histogram("queue_wait_s").observe(
-                            t_admit - t0)
-                    tok = self._sample_row(lg[j], i, 0)
-                    last[j] = tok
-                    if rec:
-                        tracer.instant("first_token", "request",
-                                       SLOT_TID0 + j, req_idx=i)
-                        self.metrics.histogram("ttft_s").observe(
-                            time.perf_counter() - t0)
-                    emit(j, tok)
+                        adm["end_args"] = {"admitted": len(take)}
+                    self.prefills += 1
+                    self.batch_steps += 1
+                    self.wasted_slot_steps += B - len(take)
+                    self.refills += sum(used[j] for j, _, _ in take)
+                    for j, _, _ in take:
+                        used[j] = True
+                    with _phase_span(self, tracer, "engine.scatter"):
+                        cache = scatter_cache_slots(cache, rcache,
+                                                    [j for j, _, _ in take],
+                                                    self._axes)
+                    with _phase_span(self, tracer, "engine.prefill_wait"):
+                        lg = np.asarray(logits)
+                    t_first = time.perf_counter()
+                    with _phase_span(self, tracer, "engine.first_tokens"):
+                        for j, i, r in take:
+                            live[j] = _Slot(req_idx=i,
+                                            prompt_len=len(r.prompt),
+                                            budget=r.max_new_tokens,
+                                            eos_id=r.eos_id)
+                            pos[j] = S0
+                            if rec:
+                                tracer.set_thread_name(SLOT_TID0 + j,
+                                                       f"slot {j}")
+                                tracer.begin("request", "request",
+                                             SLOT_TID0 + j, req_idx=i,
+                                             prompt_len=len(r.prompt),
+                                             budget=r.max_new_tokens)
+                                self.metrics.histogram(
+                                    "queue_wait_s").observe(t_admit - t0)
+                            tok = self._sample_row(lg[j], i, 0)
+                            last[j] = tok
+                            if rec:
+                                tracer.instant("first_token", "request",
+                                               SLOT_TID0 + j, req_idx=i)
+                                self.metrics.histogram("ttft_s").observe(
+                                    time.perf_counter() - t0)
+                            emit(j, tok, t_first)
                 admitted = True
                 if rec:
                     tracer.counter("slots_occupied",
@@ -545,61 +593,64 @@ class ContinuousEngine(_EngineBase):
             if all(s is None for s in live):
                 break
 
-            if admitted or tok_dev is None:
-                # push the host mirrors once per refill round, not per tick
-                tok_dev = jnp.asarray(last[:, None], jnp.int32)
-                pos_dev = jnp.asarray(pos, jnp.int32)
-
             # Greedy slots with no live EOS can only leave the batch at a
             # known budget boundary: run the fused tick (step + argmax +
             # clock advance) up to that boundary with no host feedback, so
             # dispatches pipeline like the static engine's inner loop; one
-            # sync then settles the whole span.  EOS-bearing or sampled
+            # settle then takes the whole span.  EOS-bearing or sampled
             # slots need per-tick feedback (k = 1).
             alive = [s for s in live if s is not None]
             if self.temperature <= 0.0 and all(s.eos_id < 0 for s in alive):
                 k = min(s.budget - len(s.emitted) for s in alive)
             else:
                 k = 1
-            n_free = sum(s is None for s in live)
-            cm = (_phase_span(self, tracer, "decode.span", fn=self._tick,
-                              k=k, slots=B - n_free)
-                  if rec else nullcontext({}))
-            with cm as st:
-                pend = []
-                for _ in range(k):
-                    tok_dev, logits, pos_dev, cache = self._tick(
-                        self.params, tok_dev, pos_dev, cache)
-                    pend.append(tok_dev)
-                    self.batch_steps += 1
-                    self.wasted_slot_steps += n_free
-                # the settle belongs to the span: span duration then covers
-                # device work, not just dispatch
-                if self.temperature <= 0.0:
-                    span = [np.asarray(t)[:, 0] for t in pend]
-                else:  # k == 1: per-slot RNG sampling overrides argmax token
-                    lg = np.asarray(logits)
-                    toks = last.copy()
-                    for j in range(B):
-                        if live[j] is not None:
-                            toks[j] = self._sample_row(lg[j],
-                                                       live[j].req_idx,
-                                                       len(live[j].emitted))
-                    tok_dev = jnp.asarray(toks[:, None], jnp.int32)
-                    span = [toks]
-            if rec and B > n_free:
-                # every live slot got one token per tick, k ticks per span
-                itl = st["dur_s"] / k
-                hist = self.metrics.histogram("itl_s")
-                for _ in range(k * (B - n_free)):
-                    hist.observe(itl)
-            for toks in span:
-                for j in range(B):
-                    s = live[j]
-                    if s is None:
-                        continue  # drained queue: slot decodes garbage
-                    last[j] = toks[j]
-                    emit(j, int(toks[j]))
+            n_free = B - len(alive)
+            with _phase_span(self, tracer, "engine.decode", fn=self._tick,
+                             k=k, slots=len(alive)):
+                t_span = time.perf_counter()
+                with _phase_span(self, tracer, "engine.dispatch"):
+                    if admitted or tok_dev is None:
+                        # push the host mirrors once per refill round, not
+                        # per tick
+                        tok_dev = jnp.asarray(last[:, None], jnp.int32)
+                        pos_dev = jnp.asarray(pos, jnp.int32)
+                    pend = []
+                    for _ in range(k):
+                        tok_dev, logits, pos_dev, cache = self._tick(
+                            self.params, tok_dev, pos_dev, cache)
+                        pend.append(tok_dev)
+                        self.batch_steps += 1
+                        self.wasted_slot_steps += n_free
+                with _phase_span(self, tracer, "engine.settle"):
+                    if self.temperature <= 0.0:
+                        span = [np.asarray(t)[:, 0] for t in pend]
+                    else:
+                        lg = np.asarray(logits)
+                t_settled = time.perf_counter()
+                if rec:
+                    # every live slot got one token per tick, k ticks per
+                    # span
+                    itl = (t_settled - t_span) / k
+                    hist = self.metrics.histogram("itl_s")
+                    for _ in range(k * len(alive)):
+                        hist.observe(itl)
+                with _phase_span(self, tracer, "engine.emit"):
+                    if self.temperature > 0.0:
+                        # k == 1: per-slot RNG sampling overrides the argmax
+                        toks = last.copy()
+                        for j in range(B):
+                            if live[j] is not None:
+                                toks[j] = self._sample_row(
+                                    lg[j], live[j].req_idx,
+                                    len(live[j].emitted))
+                        tok_dev = jnp.asarray(toks[:, None], jnp.int32)
+                        span = [toks]
+                    for toks in span:
+                        for j in range(B):
+                            if live[j] is None:
+                                continue  # drained queue: slot decodes garbage
+                            last[j] = toks[j]
+                            emit(j, int(toks[j]), t_settled)
             pos += k
             if rec:
                 tracer.counter("slots_occupied",

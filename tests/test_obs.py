@@ -32,7 +32,12 @@ from repro import obs
 from repro.approx import ApproxConfig
 from repro.models import build_model
 from repro.obs.report import diff_summaries, render_summary, span_stats
-from repro.serving.engine import ContinuousEngine, DecodeEngine
+from repro.serving.engine import (
+    ENGINE_SPANS,
+    WAIT_SPANS,
+    ContinuousEngine,
+    DecodeEngine,
+)
 
 from tests.test_archs import reduced
 from tests.test_serving import mixed_requests
@@ -225,8 +230,8 @@ class TestEngineTraces:
         assert validate_trace(doc) == []
         evs = doc["traceEvents"]
         names = {e["name"] for e in evs}
-        assert {"request", "first_token", "refill.prefill", "refill.scatter",
-                "decode.span", "slots_occupied", "serve.begin"} <= names
+        assert {"request", "first_token", "slots_occupied",
+                *ENGINE_SPANS} <= names
         # one balanced request B/E pair per served request, on slot tracks
         req_b = [e for e in evs if e["name"] == "request" and e["ph"] == "B"]
         req_e = [e for e in evs if e["name"] == "request" and e["ph"] == "E"]
@@ -278,6 +283,79 @@ class TestEngineTraces:
         assert eng.compile_counts() == counts_off
         for a, b in zip(base, traced):
             assert np.array_equal(a.tokens, b.tokens)
+
+    @pytest.mark.parametrize("obs_on", [False, True], ids=["obs_off", "obs_on"])
+    def test_serve_never_blocks(self, tiny_model, monkeypatch, obs_on):
+        """The engine's spans add no sync: ContinuousEngine.serve calls
+        ``jax.block_until_ready`` nowhere, with ScopeKit on or off."""
+        model, params = tiny_model
+        eng = ContinuousEngine(model, params, batch_size=2, cache_len=32)
+        eng.serve(fixed_queue())  # warm
+        calls = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: calls.append(1) or real(x))
+        obs.configure(enabled=obs_on)
+        results = eng.serve(fixed_queue())
+        obs.configure(enabled=False)
+        assert all(r is not None for r in results)
+        assert calls == []
+
+    def test_serve_annotations_follow_taxonomy(self, tiny_model, monkeypatch):
+        """The profiler spans one serve opens are the documented engine
+        phases, each inside its documented parent, properly nested, the same
+        with ScopeKit on and off; and with ScopeKit on its engine track
+        carries the same spans in the same order."""
+        model, params = tiny_model
+        eng = ContinuousEngine(model, params, batch_size=2, cache_len=32)
+        base = eng.serve(fixed_queue())  # warm
+        counts = eng.compile_counts()
+        log = []
+
+        class Recorder:
+            def __init__(self, name, **args):
+                self.name, self.args = name, args
+
+            def __enter__(self):
+                log.append(("B", self.name, self.args))
+
+            def __exit__(self, *exc):
+                log.append(("E", self.name, {}))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+        seen = {}
+        for obs_on in (False, True):
+            del log[:]
+            obs.configure(enabled=obs_on)
+            tr = obs.reset_tracer()
+            results = eng.serve(fixed_queue())
+            obs.configure(enabled=False)
+            for a, b in zip(base, results):
+                assert np.array_equal(a.tokens, b.tokens)
+            seen[obs_on] = list(log)
+            if obs_on:
+                track = [(e["ph"], e["name"]) for e in tr.events
+                         if e.get("tid") == 0 and e["ph"] in "BE"]
+                assert track == [(ph, n) for ph, n, _ in log]
+        assert eng.compile_counts() == counts
+        assert seen[False] == seen[True]
+        stack = []
+        for ph, name, args in seen[False]:
+            if ph == "B":
+                assert name in ENGINE_SPANS
+                assert ENGINE_SPANS[name] == (stack[-1] if stack else None)
+                stack.append(name)
+            else:
+                assert stack.pop() == name
+        assert stack == []
+        opened = [(n, a) for ph, n, a in seen[False] if ph == "B"]
+        assert {n for n, _ in opened} == set(ENGINE_SPANS)
+        assert set(WAIT_SPANS) < set(ENGINE_SPANS)
+        width = max(len(r.prompt) for r in fixed_queue())
+        assert opened[0] == ("engine.serve", {"requests": 6, "batch": 2,
+                                              "prefill_len": width})
+        assert all(set(a) == {"k", "slots"} for n, a in opened
+                   if n == "engine.decode")
 
     def test_static_engine_records_latency(self, tiny_model):
         model, params = tiny_model

@@ -255,6 +255,34 @@ class TestContinuousEngine:
             assert [r.steps for r in res] == [3, 0, 2, 0]
             assert res[1].tokens.size == 0 and res[3].tokens.size == 0
 
+    @pytest.mark.parametrize("temperature", [0.0, 1.0])
+    def test_on_token_fires_once_per_served_token(self, tiny_model,
+                                                  temperature):
+        """``on_token(req_idx, n, t)`` fires once per served token, in order
+        from ``n == 0``, with non-decreasing settle times inside the serve
+        call, never for a zero-budget request, and the tokens are those of a
+        serve without the hook."""
+        import time
+
+        model, params = tiny_model
+        reqs = mixed_requests(np.random.default_rng(11), 7)
+        reqs.insert(3, Request(prompt=reqs[0].prompt, max_new_tokens=0))
+        eng = ContinuousEngine(model, params, 2, 64, temperature=temperature,
+                               seed=3)
+        base = eng.serve(reqs)
+        calls = {}
+        t0 = time.perf_counter()
+        out = eng.serve(reqs, on_token=lambda i, n, t: calls.setdefault(
+            i, []).append((n, t)))
+        t1 = time.perf_counter()
+        for i, (a, b) in enumerate(zip(base, out)):
+            np.testing.assert_array_equal(a.tokens, b.tokens)
+            got = calls.get(i, [])
+            assert [n for n, _ in got] == list(range(len(b.tokens))), i
+            ts = [t for _, t in got]
+            assert all(t0 <= x <= y <= t1 for x, y in zip(ts, ts[1:] + [t1]))
+        assert 3 not in calls
+
     def test_engine_batch_size_mismatch_rejected(self, tiny_model):
         model, params = tiny_model
         eng = DecodeEngine(model, params, 2, 64)
