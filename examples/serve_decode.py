@@ -124,7 +124,7 @@ def main():
           f"/ {total} tokens in {dt:.2f}s ({total / dt:.1f} tok/s wall, "
           f"{total / steady:.1f} tok/s steady after "
           f"{engine.compile_time_s:.2f}s compile, CPU); "
-          f"{engine.batch_steps} batch rounds, "
+          f"{engine.batch_steps:g} batch rounds, "
           f"wasted slot-step fraction {engine.wasted_fraction:.2f}")
     for i, r in enumerate(results[:3]):
         print(f"  req{i}: prompt={r.prompt_len} toks -> {r.tokens.tolist()}")

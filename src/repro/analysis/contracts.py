@@ -588,10 +588,12 @@ def engine_stationarity_findings(batch: int = 2, cache_len: int = 32,
     out.append(Finding("recompile_hazard", f"engine:{aid}:tick-weak-types",
                        not weak, f"weak-typed tick outputs: {weak[:4]}"))
 
-    # one prefill executable: refill reuses the same (B, S0) signature and
-    # must return a cache with the original avals (scatter target)
-    toks = jax.ShapeDtypeStruct((batch, prefill_len), jnp.int32)
-    _, pcache = jax.eval_shape(model.prefill, params, {"tokens": toks}, cache)
+    # one prefill executable: every admission calls the engine's own
+    # slot-addressed one-row prefill with the same (B, S0) block and a traced
+    # slot, and it must return the live cache's avals (it updates it in place)
+    batch_in = {"tokens": jax.ShapeDtypeStruct((batch, prefill_len), jnp.int32),
+                "slot": jax.ShapeDtypeStruct((), jnp.int32)}
+    _, pcache = jax.eval_shape(eng._prefill, params, batch_in, cache)
     psig = jax.tree_util.tree_map(lambda s: (s.shape, str(s.dtype)), pcache)
     out.append(Finding("recompile_hazard", f"engine:{aid}:prefill-stationary",
                        psig == sig,

@@ -147,7 +147,7 @@ def main():
           f"in {dt:.2f}s ({total_new / dt:.1f} tok/s wall, "
           f"{total_new / steady:.1f} tok/s steady after "
           f"{engine.compile_time_s:.2f}s compile, {args.scheduler}); "
-          f"{engine.batch_steps} batch rounds, wasted slot-step fraction "
+          f"{engine.batch_steps:g} batch rounds, wasted slot-step fraction "
           f"{engine.wasted_fraction:.2f}")
     for i, r in enumerate(results[:4]):
         print(f"  req{i}: prompt_len={r.prompt_len} steps={r.steps} "

@@ -12,11 +12,11 @@ loop to the group-wide max).
 ``ContinuousEngine`` / ``serve_continuous`` — an admission queue with
 mid-stream slot refill: every batch slot carries its own request state
 (budget, EOS id, RNG stream, absolute position clock).  When a slot finishes,
-the host prefills the next queued request (one fixed-shape prefill whose rows
-serve every slot freed that round) and scatters the freed slots' rows of the
-fresh cache into the live cache — the same two jitted executables
-(``prefill``, ``decode_step``) serve the whole queue, with zero recompiles
-across refills (per-slot positions keep every decode tick at one shape).
+the host prefills the next queued request alone (one one-row prefill per
+admitted request, written into its slot of the live cache in place) — the
+same two jitted executables (``prefill``, ``decode_step``) serve the whole
+queue, with zero recompiles across refills (a traced slot index keeps every
+prefill at one shape, per-slot positions every decode tick).
 
 Result accounting is per-request: ``Result.tokens`` is truncated at the
 request's own first EOS (inclusive) and ``Result.steps`` counts the tokens
@@ -83,13 +83,13 @@ class _EngineBase:
     decode step); it counts as wasted when it yields no token for a live
     request.  Caveat the serve bench documents: the static prefill round
     counts every slot as useful (a padding dummy's first token is kept by its
-    1-token budget even though the caller never sees it), while a continuous
-    refill round charges every non-admitted row — both distortions make the
+    1-token budget even though the caller never sees it), which makes the
     static number look BETTER, so the continuous-vs-static gate is
-    conservative."""
+    conservative.  The continuous engine prefills one row per call, so it
+    counts each as ``1/B`` of a round, with no slot wasted."""
 
     def reset_counters(self) -> None:
-        self.batch_steps = 0  # sampling rounds (prefill rounds + decode steps)
+        self.batch_steps = 0  # sampling rounds of B rows (prefill + decode)
         self.wasted_slot_steps = 0
         self.compile_time_s = 0.0  # wall time inside compile-flagged spans
         metrics = getattr(self, "metrics", None)
@@ -112,8 +112,7 @@ class _EngineBase:
 ENGINE_SPANS = {
     "engine.serve": None,  # one serve() call
     "engine.admit": "engine.serve",  # one admission round
-    "engine.prefill": "engine.admit",  # row build, H2D, prefill dispatch
-    "engine.scatter": "engine.admit",  # dispatch of the eager slot scatter
+    "engine.prefill": "engine.admit",  # per admitted row: build, H2D, dispatch
     "engine.prefill_wait": "engine.admit",  # host waits on prefill logits
     "engine.first_tokens": "engine.admit",  # sampling + first emits
     "engine.decode": "engine.serve",  # one span of k fused ticks
@@ -348,9 +347,9 @@ def cache_batch_axes(model, cache_len: int):
 
 
 def scatter_cache_slots(dst, src, slot_ids: Sequence[int], axes):
-    """dst[..., slot, ...] = src[..., slot, ...] for each refilled slot, per
-    leaf along its batch axis.  Host-orchestrated (eager ops, outside jit), so
-    refill never touches the decode executable."""
+    """dst[..., slot, ...] = src[..., slot, ...] for each listed slot, per
+    leaf along its batch axis, as eager ops.  The serve loop does not use it
+    (its prefill writes the admitted slot in place)."""
     sl = jnp.asarray(list(slot_ids), jnp.int32)
 
     def one(d, s, ax):
@@ -373,13 +372,16 @@ class ContinuousEngine(_EngineBase):
     """Admission queue + per-slot lifecycle + mid-stream slot refill.
 
     Every prompt is left-padded to one fixed prefill width (``prefill_len``,
-    default: the queue's longest prompt), so admission — initial fill and
-    every refill — reuses ONE compiled prefill; per-slot position clocks keep
-    every decode tick at one shape, so the whole queue is served by exactly
-    two executables (assert via ``compile_counts()``).  Greedy output is
-    token-identical to serving each request alone (per-request oracle): slot
-    rows never interact, and a refilled slot's scattered cache rows are
-    exactly the rows a solo prefill would have produced.
+    default: the queue's longest prompt).  Admission — initial fill and every
+    refill — prefills each admitted request alone: one call of ONE compiled
+    prefill per request, which computes that one row and writes it into its
+    slot of the live cache in place (the slot index is traced, so every call
+    has the same shapes); per-slot position clocks keep every decode tick at
+    one shape, so the whole queue is served by exactly two executables
+    (assert via ``compile_counts()``).  Greedy output is token-identical to
+    serving each request alone (per-request oracle): slot rows never
+    interact, and a refilled slot's cache rows are exactly the rows a solo
+    prefill produces.
 
     Token-only prompts (models whose prefill needs extra inputs — encoder
     frames, vision patches — are served by ``serve_static`` only).
@@ -399,7 +401,27 @@ class ContinuousEngine(_EngineBase):
         self.pad_id = pad_id
         self.metrics = obs.Registry()  # ttft_s / itl_s / queue_wait_s
         _place_engine_packs(model, mesh)
-        self._prefill = jax.jit(model.prefill)
+        self._axes = axes = cache_batch_axes(model, cache_len)
+
+        # One admitted request per call: row ``slot`` of ``tokens`` (the other
+        # rows are pad) is prefilled alone against a one-row cache, which then
+        # overwrites row ``slot`` of every leaf of the live cache, position
+        # rows included.  The live cache is donated, so it is updated in
+        # place; the logits are (B, V) with the admitted row at ``slot`` and
+        # zeros elsewhere.
+        def prefill(params, batch, cache):
+            slot = batch["slot"]
+            row = jax.lax.dynamic_slice_in_dim(batch["tokens"], slot, 1)
+            logits, rcache = model.prefill(params, {"tokens": row},
+                                           model.init_cache(1, cache_len))
+            cache = jax.tree.map(
+                lambda d, s, ax: jax.lax.dynamic_update_slice_in_dim(
+                    d, s.astype(d.dtype), slot, ax), cache, rcache, axes)
+            out = jnp.zeros((batch_size,) + logits.shape[1:], logits.dtype)
+            return jax.lax.dynamic_update_slice_in_dim(out, logits, slot,
+                                                       0), cache
+
+        self._prefill = jax.jit(prefill, donate_argnums=2)
 
         # One fused executable per decode tick: step + greedy argmax + clock
         # advance, with the fed-back token and the per-slot positions staying
@@ -413,13 +435,13 @@ class ContinuousEngine(_EngineBase):
         self._tick = jax.jit(tick)
         self._executables = {"prefill": self._prefill,
                              "decode_step": self._tick}
-        self._axes = None
-        self._fresh = None
+        self._fresh = None  # the empty B-row cache every queue starts from
         self.reset_counters()
 
     def reset_counters(self) -> None:
         super().reset_counters()
-        self.prefills = 0
+        self.prefills = 0  # prefill work in (B, S0) prefills: rows / B
+        self.prefill_rows = 0  # rows prefilled: one per admitted request
         self.refills = 0  # admissions into a previously-used slot
 
     # ------------------------------ sampling ---------------------------------
@@ -458,8 +480,6 @@ class ContinuousEngine(_EngineBase):
         if S0 > self.cache_len:
             raise ValueError(f"prefill width {S0} exceeds cache_len "
                              f"{self.cache_len}")
-        if self._axes is None:
-            self._axes = cache_batch_axes(self.model, self.cache_len)
         if self._fresh is None:
             self._fresh = self.model.init_cache(B, self.cache_len)
         tracer = obs.get_tracer() if obs.enabled() else None
@@ -483,7 +503,9 @@ class ContinuousEngine(_EngineBase):
         pending = deque(enumerate(requests))
         live: List[Optional[_Slot]] = [None] * B
         used = [False] * B  # slots occupied before (this call): refill marker
-        cache = self._fresh
+        # the prefill donates the live cache: start from a copy of the empty
+        # one, which stays for the next queue
+        cache = jax.tree.map(jnp.copy, self._fresh)
         pos = np.zeros((B,), np.int64)  # host mirror of the per-slot clocks
         last = np.zeros((B,), np.int64)  # host mirror of last sampled tokens
         tok_dev = None  # (B, 1) int32 device-resident fed-back token
@@ -506,10 +528,10 @@ class ContinuousEngine(_EngineBase):
                                tokens=len(s.emitted))
                 live[j] = None
 
-        def build_rows():
-            """The next admission's prompt rows and its (slot, req_idx,
-            request) triples; zero-budget requests finish on the way."""
-            rows = np.full((B, S0), self.pad_id, np.int32)
+        def take_free():
+            """The next admission's (slot, req_idx, request) triples, one per
+            free slot while the queue lasts; zero-budget requests finish on
+            the way."""
             take = []
             for j in [j for j in range(B) if live[j] is None]:
                 i, r = None, None
@@ -525,44 +547,51 @@ class ContinuousEngine(_EngineBase):
                     i, r = None, None
                 if r is None:
                     break
-                rows[j, S0 - len(r.prompt):] = r.prompt
                 take.append((j, i, r))
-            return rows, take
+            return take
 
         while True:
-            # admission: one fixed-shape prefill serves every free slot
-            # (budget-1 / instant-EOS admissions free their slot immediately,
-            # so keep refilling until slots or queue run dry)
+            # admission: one one-row prefill per free slot, back to back,
+            # each into its slot of the cache the previous call returned;
+            # one wait for all their logits (budget-1 / instant-EOS
+            # admissions free their slot immediately, so keep refilling
+            # until slots or queue run dry)
             admitted = False
             while pending and any(s is None for s in live):
                 with _phase_span(self, tracer, "engine.admit",
                                  fn=self._prefill) as adm:
                     with _phase_span(self, tracer, "engine.prefill"):
-                        rows, take = build_rows()
+                        take = take_free()
                         t_admit = time.perf_counter()
-                        if take:
-                            logits, rcache = self._prefill(
-                                self.params, {"tokens": jnp.asarray(rows)},
-                                self._fresh)
+                        outs = []
+                        for j, _, r in take:
+                            # only row j holds a prompt, so each call's
+                            # block names the one request it admits
+                            rows = np.full((B, S0), self.pad_id, np.int32)
+                            rows[j, S0 - len(r.prompt):] = r.prompt
+                            logits, cache = self._prefill(
+                                self.params,
+                                {"tokens": jnp.asarray(rows),
+                                 "slot": jnp.asarray(j, jnp.int32)}, cache)
+                            outs.append(logits)
                     if not take:
                         break
-                    if rec:
-                        adm["end_args"] = {"admitted": len(take)}
-                    self.prefills += 1
-                    self.batch_steps += 1
-                    self.wasted_slot_steps += B - len(take)
+                    # each call computes and samples its one admitted row:
+                    # 1/B of a batch round, with no slot wasted
+                    self.prefill_rows += len(take)
+                    self.prefills += len(take) / B
+                    self.batch_steps += len(take) / B
                     self.refills += sum(used[j] for j, _, _ in take)
+                    if rec:
+                        adm["end_args"] = {"admitted": len(take),
+                                           "prefill_rows": self.prefill_rows}
                     for j, _, _ in take:
                         used[j] = True
-                    with _phase_span(self, tracer, "engine.scatter"):
-                        cache = scatter_cache_slots(cache, rcache,
-                                                    [j for j, _, _ in take],
-                                                    self._axes)
                     with _phase_span(self, tracer, "engine.prefill_wait"):
-                        lg = np.asarray(logits)
+                        lgs = jax.device_get(outs)
                     t_first = time.perf_counter()
                     with _phase_span(self, tracer, "engine.first_tokens"):
-                        for j, i, r in take:
+                        for (j, i, r), lg in zip(take, lgs):
                             live[j] = _Slot(req_idx=i,
                                             prompt_len=len(r.prompt),
                                             budget=r.max_new_tokens,

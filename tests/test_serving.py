@@ -7,7 +7,7 @@ each request alone.  The oracle pads every prompt to the engine's prefill
 width and replicates it across all batch rows of the PR 1 fixed-batch engine
 (same compiled shapes — bf16 results are only bit-stable at equal shapes), so
 it goes through the old prefill + scalar-clock decode path: agreement proves
-the per-slot clocks, the refill gather/scatter, and slot isolation together.
+the per-slot clocks, the one-row slot prefill, and slot isolation together.
 
 Fast tier runs small queues on a 2-layer model (exact + one table mode); the
 full-size queues across cache families (local:global KV, SSM state, xLSTM
@@ -17,6 +17,7 @@ state) are ``slow`` and join the nightly job.
 import math
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -295,6 +296,136 @@ class TestContinuousEngine:
         eng = ContinuousEngine(model, params, 2, 64, prefill_len=4)
         with pytest.raises(ValueError, match="exceeds the prefill width"):
             eng.serve([Request(prompt=np.zeros((6,), np.int32))])
+
+
+class TestSlotPrefill:
+    """Admission prefills each admitted request alone: one call of the
+    engine's slot-addressed one-row prefill per request, written into its
+    slot of the live cache in place."""
+
+    @staticmethod
+    def _prefill_slot(eng, cache, slot, prompt, width):
+        rows = np.zeros((eng.B, width), np.int32)
+        rows[slot, width - len(prompt):] = prompt
+        return eng._prefill(eng.params, {"tokens": rows,
+                                         "slot": np.int32(slot)}, cache)
+
+    @pytest.mark.parametrize("slot", [0, 2])
+    def test_refill_leaves_other_slots_bit_identical(self, tiny_model, slot):
+        """Refilling one slot that has prefilled and decoded rewrites that
+        slot's row of every cache leaf, positions included, to what a solo
+        prefill gives, and leaves every other slot's rows bit-identical; the
+        logits hold the solo prefill's row at the slot and zeros
+        elsewhere."""
+        model, params = tiny_model
+        B, W, S0 = 3, 32, 8
+        eng = ContinuousEngine(model, params, batch_size=B, cache_len=W)
+        rng = np.random.default_rng(12)
+        cache = model.init_cache(B, W)
+        for j in range(B):  # every slot occupied, with prompts of its own
+            _, cache = self._prefill_slot(
+                eng, cache, j, rng.integers(1, 100, (S0,)).astype(np.int32),
+                S0)
+        tok, pos = jnp.ones((B, 1), jnp.int32), jnp.full((B,), S0, jnp.int32)
+        for _ in range(2):  # two decode ticks move every slot's positions on
+            tok, _, pos, cache = eng._tick(params, tok, pos, cache)
+        before = jax.device_get(cache)
+        prompt = rng.integers(1, 100, (5,)).astype(np.int32)
+        logits, cache = self._prefill_slot(eng, cache, slot, prompt, S0)
+        after = jax.device_get(cache)
+        solo_row = np.zeros((1, S0), np.int32)
+        solo_row[0, S0 - len(prompt):] = prompt
+        solo_logits, solo = jax.device_get(jax.jit(model.prefill)(
+            params, {"tokens": solo_row}, model.init_cache(1, W)))
+        others = [j for j in range(B) if j != slot]
+        for b, a, s, ax in zip(jax.tree.leaves(before), jax.tree.leaves(after),
+                               jax.tree.leaves(solo),
+                               jax.tree.leaves(eng._axes)):
+            np.testing.assert_array_equal(np.take(a, others, axis=ax),
+                                          np.take(b, others, axis=ax))
+            np.testing.assert_array_equal(np.take(a, [slot], axis=ax), s)
+            assert not np.array_equal(np.take(b, [slot], axis=ax), s)
+        logits = np.asarray(logits)
+        np.testing.assert_array_equal(logits[slot], solo_logits[0])
+        assert not logits[others].any()
+
+    def test_counters_keep_per_round_meaning(self, tiny_model):
+        """The counters keep counting sampling work in rounds of B rows:
+        ``prefill_rows`` counts the rows prefilled (one call per admitted
+        request), ``prefills`` and ``batch_steps`` count each as 1/B of a
+        round, so ``batch_steps - prefills`` is the decode ticks, and only a
+        tick wastes slots.  Budgets 3, 5, 2, 4 and a zero-budget request on
+        two slots admit 2 + 1 + 1 rows around 2 + 1 + 1 + 2 decode ticks,
+        the last two with one slot drained."""
+        model, params = tiny_model
+        eng = ContinuousEngine(model, params, batch_size=2, cache_len=32)
+        calls = []
+        real = eng._prefill
+        eng._prefill = lambda p, b, c: calls.append(int(b["slot"])) or \
+            real(p, b, c)
+        rng = np.random.default_rng(13)
+        out = eng.serve([Request(prompt=rng.integers(1, 100, (6,)).astype(
+            np.int32), max_new_tokens=m) for m in (3, 5, 2, 4, 0)])
+        assert [r.steps for r in out] == [3, 5, 2, 4, 0]
+        assert calls == [0, 1, 0, 0]
+        assert eng.prefill_rows == len(calls) == 4
+        assert eng.prefills == 4 / 2 and eng.refills == 2
+        assert eng.batch_steps - eng.prefills == 6
+        assert eng.wasted_slot_steps == 2
+        assert eng.wasted_fraction == 2 / (2 * (6 + 4 / 2))
+        eng.reset_counters()
+        assert eng.prefill_rows == 0
+
+    def test_refills_beyond_warm_slots_compile_nothing(self, tiny_model):
+        """After a warm 2-request queue, a queue whose refills land in slots
+        2 and 3 traces and compiles nothing: the slot is a traced operand,
+        so two executables serve every slot and every number admitted."""
+        model, params = tiny_model
+        eng = ContinuousEngine(model, params, batch_size=4, cache_len=32,
+                               prefill_len=8)
+        eng.serve([Request(prompt=np.arange(1, 9, dtype=np.int32),
+                           max_new_tokens=2) for _ in range(2)])
+        slots = []
+        real = eng._prefill
+        eng._prefill = lambda p, b, c: slots.append(int(b["slot"])) or \
+            real(p, b, c)
+        events = []
+
+        def on_event(name, secs, **kw):
+            if name.startswith("/jax/core/compile/"):
+                events.append(name)
+
+        rng = np.random.default_rng(14)
+        reqs = [Request(prompt=rng.integers(1, 100, (int(n),)).astype(
+            np.int32), max_new_tokens=m)
+            for n, m in zip((8, 3, 5, 7, 4, 6, 2, 8, 5),
+                            (6, 5, 4, 2, 3, 2, 4, 3, 2))]
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        try:
+            eng.serve(reqs)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_event)
+        assert set(slots[4:]) - {0, 1}, slots  # refills into slots 2 / 3
+        assert events == []
+        counts = eng.compile_counts()
+        if -1 not in counts.values():
+            assert counts == {"prefill": 1, "decode_step": 1}, counts
+
+    def test_serves_repeat_and_keep_the_empty_cache(self, tiny_model):
+        """The prefill donates the live cache, never the empty one every
+        queue starts from: two serves in a row give the same tokens, and
+        ``_fresh`` is still readable, and still empty, after them."""
+        model, params = tiny_model
+        eng = ContinuousEngine(model, params, batch_size=2, cache_len=32)
+        reqs = mixed_requests(np.random.default_rng(15), 6)
+        first = eng.serve(reqs)
+        second = eng.serve(reqs)
+        for a, b in zip(first, second):
+            np.testing.assert_array_equal(a.tokens, b.tokens)
+        fresh = jax.device_get(eng._fresh)
+        empty = jax.device_get(model.init_cache(2, 32))
+        for got, want in zip(jax.tree.leaves(fresh), jax.tree.leaves(empty)):
+            np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.slow
