@@ -3,9 +3,13 @@
 Everything that belongs to one configuration, traffic mix or metric is a file
 that this module finds by name: ``configs/<config>.json``,
 ``traffic/<traffic>.json``, ``limits/<workload>.json`` and
-``metrics/<metric>.py``, under this directory.
+``metrics/<metric>.py``, under this directory.  The architecture comes from the
+family that the configuration file names, ``families/<family>.py`` (see
+``family``): the program's configuration, the reference, the weights' scales
+and the counts of useful work.  No other module knows an architecture's keys
+or leaves.
 
-Set-up builds the model in the configuration's approximation mode, makes its
+Set-up builds the model that the family makes of the configuration, makes its
 weights on the device from the seed (``weights.py``), builds one
 ``ContinuousEngine`` with ``prefill_len`` fixed to the mix's prefill width,
 and warms both of its executables with one short queue, plus the slot
@@ -54,6 +58,54 @@ def log(msg: str) -> None:
 # ------------------------------- the cell ------------------------------------
 
 
+def load_module(path: Path, name: str):
+    """The Python file at ``path``, loaded as a module of its own."""
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(name: str):
+    """The architecture module ``families/<name>.py``.  It holds
+
+    * ``program_config(config)``: the program's ``ArchConfig`` for the
+      configuration file ``config``, at the depth (and share of experts) the
+      file states;
+    * ``logits(params, tokens, config, first, dot)``: the plain float32
+      reference, logits (T - first, vocab) of ``tokens`` (T,), with every
+      weight product through ``dot`` (``precision.py``);
+    * ``scale(names, shape)``: the init scale of the weight leaf at the path
+      of keys ``names``, or ``None`` for a norm gain (ones);
+    * ``request_flops(config, prompt_len, n_out)``: useful FLOPs of one
+      served request (``step_mfu``);
+    * ``act_elements(config, counters)``: elements the MLP activation is
+      applied to in a window with the engine counters ``counters``
+      (``act_kernel_roofline``);
+    * ``COUNTERS``: engine attributes that ``window`` records in the run's
+      ``counters`` beside the common ones."""
+    return load_module(HERE / "families" / f"{name}.py",
+                       "chipbench_family_" + name)
+
+
+def load_config(name: str) -> tuple:
+    """``(config, family module)``: the configuration file
+    ``configs/<name>.json`` and the family it names.  A file that names no
+    family, or one that has no module, is refused."""
+    path = HERE / "configs" / f"{name}.json"
+    config = load_json(path)
+    where = path.relative_to(REPO)
+    fam = config.get("family")
+    if not isinstance(fam, str) or not fam.isidentifier():
+        raise SystemExit(f"{where} names no family: it needs \"family\", the "
+                         "name of a module in chipbench/families/")
+    if not (HERE / "families" / f"{fam}.py").is_file():
+        raise SystemExit(f"{where} names the family {fam!r}, which has no "
+                         f"module chipbench/families/{fam}.py")
+    return config, family(fam)
+
+
 def load_cell(workload: str, trace: bool) -> dict:
     bench = load_json(REPO / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -64,9 +116,11 @@ def load_cell(workload: str, trace: bool) -> dict:
     kind = "per_layer" if trace else "end_to_end"
     metrics = [m for m in bench[kind]
                if workload in m.get("workloads", [workload])]
+    config, fam = load_config(w["config"])
     return {
         "workload": w,
-        "config": load_json(HERE / "configs" / f"{w['config']}.json"),
+        "config": config,
+        "family": fam,
         "traffic": load_json(HERE / "traffic" / f"{w['traffic']}.json"),
         "limits": load_json(HERE / "limits" / f"{workload}.json"),
         "metrics": metrics,
@@ -90,26 +144,6 @@ def find_chip(want: int) -> dict:
 
 
 # ------------------------------- set-up --------------------------------------
-
-
-def arch_config(config: dict):
-    """The program's configuration for a configuration file."""
-    from repro.models import get_config
-
-    cfg = get_config(config["arch"])
-    run = config["run"]
-    if (cfg.act, cfg.mlp_kind) != (run["act"], run["mlp"]):
-        raise ValueError(f"{config['arch']}: the program runs {cfg.act}/"
-                         f"{cfg.mlp_kind}, the file says {run['act']}/"
-                         f"{run['mlp']}")
-    return cfg.replace(
-        n_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
-        d_ff=config["intermediate_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"], vocab=config["vocab_size"],
-        param_dtype=run["param_dtype"], compute_dtype=run["compute_dtype"],
-        approx=dataclasses.replace(cfg.approx, mode=run["approx_mode"],
-                                   e_a=run["e_a"]))
 
 
 class CompileCounter:
@@ -137,11 +171,11 @@ def build(cell: dict, seed: int) -> Served:
     from repro.models import build_model
     from repro.serving.engine import ContinuousEngine
 
-    mix = cell["traffic"]
-    model = build_model(arch_config(cell["config"]))
+    mix, fam = cell["traffic"], cell["family"]
+    model = build_model(fam.program_config(cell["config"]))
     shapes = model.abstract_params()
-    engine = ContinuousEngine(model, weights.make(shapes, seed), mix["batch"],
-                              mix["cache_len"], temperature=0.0,
+    engine = ContinuousEngine(model, weights.make(shapes, seed, fam.scale),
+                              mix["batch"], mix["cache_len"], temperature=0.0,
                               prefill_len=mix["prefill_len"])
     warm(engine)
     return Served(engine, shapes)
@@ -268,7 +302,9 @@ def window(served: Served, cell: dict, seed: int, seconds: float,
                          "wasted_slot_steps": engine.wasted_slot_steps,
                          "refills": engine.refills,
                          "batch": engine.B,
-                         "prefill_len": engine.prefill_len},
+                         "prefill_len": engine.prefill_len,
+                         **{name: getattr(engine, name)
+                            for name in cell["family"].COUNTERS}},
             "executables": engine.compile_counts()}
 
 
@@ -301,12 +337,8 @@ def failed(requests: list, vocab: int) -> int:
 
 
 def metric_reader(name: str):
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(HERE / "metrics" / f"{name}.py",
+                       "chipbench_metric_" + name).read
 
 
 def read_metrics(cell: dict, run: dict) -> dict:
@@ -324,7 +356,7 @@ def read_metrics(cell: dict, run: dict) -> dict:
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool, chip: dict,
              t_start: float) -> dict:
     """One run; returns the result object the last line of output holds."""
-    config, mix = cell["config"], cell["traffic"]
+    config = cell["config"]
     compiles = CompileCounter()
     served = build(cell, seed)
     setup_s = time.perf_counter() - t_start
@@ -353,17 +385,16 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, chip: dict,
 
     t_check = time.perf_counter()
     n_failed = failed(requests, config["vocab_size"])
-    params = weights.make(shapes, seed)
-    total = mix["prefill_len"] + mix["output"]["max"] - 1
-    values = check.readings(params, config, mix["prefill_len"], total,
-                            picked)["program"]
+    params = weights.make(shapes, seed, cell["family"].scale)
+    values = check.readings(params, cell, picked)["program"]
     del params
     correct, checks = check.verdict(values, cell["limits"], n_failed)
     log(f"check: {len(picked)} requests, "
         f"{sum(len(r['out']) for r in picked)} served tokens against the "
         f"float32 reference in {time.perf_counter() - t_check:.1f} s")
 
-    run = {"config": config, "traffic": mix, "peak": chip["peak"],
+    run = {"config": config, "family": cell["family"],
+           "traffic": cell["traffic"], "peak": chip["peak"],
            "chips": chip["count"], "setup_s": setup_s, "trace": tr, **win}
     result = {"correct": bool(correct), "attempted": len(requests),
               "failed": n_failed, "metrics": read_metrics(cell, run),

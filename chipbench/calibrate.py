@@ -5,9 +5,11 @@
 In one process (set-up and compiles are paid once), for each seed: weights
 from the seed, one queue of the cell's mix served through the timed path
 (``bench.window``, which records the program's logits), then the check's
-sample of its requests compared with the float32 reference through the
-comparison that decides a run's ``correct`` (``check.readings`` and
-``check.verdict``, with the cell's limits).  It prints per seed, for each of
+sample of its requests compared with the float32 reference of the
+configuration's family through the comparison that decides a run's
+``correct`` (``check.readings`` and ``check.verdict``, with the cell's
+limits).  The controls are the family's reference with its weight products in
+a lower precision (``precision.CONTROLS``).  It prints per seed, for each of
 
 * ``program``: the program's readings, the lower end of each limit;
 * ``fp8``: the control, the reference computed in float8 e4m3 in the
@@ -43,20 +45,17 @@ STAND_INS = ("program", "fp8", "int8", "altered_token")
 def readings(served: bench.Served, cell: dict, seed: int) -> dict:
     """One seed: a queue through the timed path, then every stand-in's
     numbers and verdict."""
-    mix = cell["traffic"]
-    engine = served.engine
+    engine, scale = served.engine, cell["family"].scale
     engine.params = None
     gc.collect()
-    engine.params = weights.make(served.shapes, seed)
+    engine.params = weights.make(served.shapes, seed, scale)
     win = bench.window(served, cell, seed, 0.0, None)
     picked = bench.picked_with_logits(win, cell, seed)
     engine.params = None
     gc.collect()
     n_failed = bench.failed(win["requests"], cell["config"]["vocab_size"])
-    params = weights.make(served.shapes, seed)
-    total = mix["prefill_len"] + mix["output"]["max"] - 1
-    got = check.readings(params, cell["config"], mix["prefill_len"], total,
-                         picked, STAND_INS)
+    params = weights.make(served.shapes, seed, scale)
+    got = check.readings(params, cell, picked, STAND_INS)
     out = {"failed": n_failed, "tokens": sum(len(r["out"]) for r in picked)}
     for name, values in got.items():
         correct, _ = check.verdict(values, cell["limits"], n_failed)

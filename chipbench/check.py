@@ -4,8 +4,9 @@ The window records the logits that the engine's own prefill and tick
 executables return while they serve the last queue (``bench.Recorder``).
 Once the window has closed, a sample of that queue's requests, drawn from the
 seed and always holding the one with the most output, is run through the
-float32 reference: the prompt row exactly as the engine prefilled it (left-
-padded to the prefill width with the pad token) followed by the served tokens.
+float32 reference of the configuration's family (``families/<family>.py``
+``logits``): the prompt row exactly as the engine prefilled it (left-padded to
+the prefill width with the pad token) followed by the served tokens.
 At each position that produced a served token two numbers are read:
 
 * ``logit_gap``: how far the served token's reference logit lies below the
@@ -26,7 +27,7 @@ from __future__ import annotations
 
 import numpy as np
 
-import reference
+import precision
 import traffic
 
 PAD_ID = 0  # the engine's pad token (ContinuousEngine's default)
@@ -52,13 +53,17 @@ def sequence(prompt: np.ndarray, out: np.ndarray, width: int, total: int):
     return seq
 
 
-def served_logits(params, cfg: dict, width: int, total: int, r: dict,
-                  dot=reference.f32_dot) -> np.ndarray:
+def served_logits(params, cell: dict, r: dict,
+                  dot=precision.f32_dot) -> np.ndarray:
     """The reference's logits (n, vocab) at the ``n`` positions that produced
-    request ``r``'s served tokens."""
+    request ``r``'s served tokens, with the cell's prefill width and room for
+    the mix's longest output."""
+    mix = cell["traffic"]
+    width = mix["prefill_len"]
+    total = width + mix["output"]["max"] - 1
     seq = sequence(r["prompt"], r["out"], width, total)
-    return np.asarray(reference.logits(params, seq, cfg, width - 1,
-                                       dot=dot))[:len(r["out"])]
+    return np.asarray(cell["family"].logits(params, seq, cell["config"],
+                                            width - 1, dot))[:len(r["out"])]
 
 
 def numbers(ref: np.ndarray, got: np.ndarray, served: np.ndarray) -> dict:
@@ -78,7 +83,7 @@ def stand_in(r: dict, ref: np.ndarray, name: str, vocab: int,
     served token moved one id on where it is produced."""
     out = np.asarray(r["out"])
     got = r.get("logits")
-    if name in reference.CONTROLS:
+    if name in precision.CONTROLS:
         return low, low.argmax(-1)
     if got is None or got.shape != ref.shape:
         got = np.full(ref.shape, np.finfo(np.float32).max)  # none recorded
@@ -87,19 +92,19 @@ def stand_in(r: dict, ref: np.ndarray, name: str, vocab: int,
     return got, out
 
 
-def readings(params, cfg: dict, width: int, total: int, picked: list,
+def readings(params, cell: dict, picked: list,
              names: tuple = ("program",)) -> dict:
     """For each of ``names`` (see ``stand_in``), the widest of each number
     over the ``picked`` requests (dicts with ``prompt``, ``out`` and the
     program's ``logits``), all at the same served positions."""
     worst = {n: {"logit_gap": 0.0, "logit_err": 0.0} for n in names}
+    vocab = cell["config"]["vocab_size"]
     for r in picked:
-        ref = served_logits(params, cfg, width, total, r)
+        ref = served_logits(params, cell, r)
         for n in names:
-            low = (served_logits(params, cfg, width, total, r,
-                                 dot=reference.CONTROLS[n])
-                   if n in reference.CONTROLS else None)
-            got, served = stand_in(r, ref, n, cfg["vocab_size"], low)
+            low = (served_logits(params, cell, r, precision.CONTROLS[n])
+                   if n in precision.CONTROLS else None)
+            got, served = stand_in(r, ref, n, vocab, low)
             for k, v in numbers(ref, got, served).items():
                 worst[n][k] = max(worst[n][k], v)
     return worst

@@ -2,12 +2,13 @@
 
   JAX_PLATFORMS=cpu python3 chipbench/rehearse.py --workload <cell> [--layers 8 10 12]
 
-For each depth it compiles the ``ContinuousEngine``'s prefill and decode tick
-at the cell's batch, prefill width and cache length from shapes alone, and
-prints ``memory_analysis()`` with the peak the serve loop would hold: the
-larger executable plus the two caches that stay alive beside it (the
-engine's zero cache and the last prefill's).  What the compiler refuses here
-costs no chip time.  Nothing runs, so no time is measured.
+For each depth it compiles the ``ContinuousEngine``'s prefill (one admitted
+row, addressed by its slot) and decode tick at the cell's batch, prefill width
+and cache length from shapes alone, with the model that the configuration's
+family makes, and prints ``memory_analysis()`` with the peak the serve loop
+would hold: the larger executable plus the two caches that stay alive beside
+it (the engine's zero cache and the last prefill's).  What the compiler
+refuses here costs no chip time.  Nothing runs, so no time is measured.
 """
 
 from __future__ import annotations
@@ -46,15 +47,15 @@ def sizes(cell: dict, n_layers: int, device) -> dict:
     mix = cell["traffic"]
     B, S, W = mix["batch"], mix["prefill_len"], mix["cache_len"]
     config = dict(cell["config"], num_hidden_layers=n_layers)
-    model = build_model(bench.arch_config(config))
+    model = build_model(cell["family"].program_config(config))
     sh = SingleDeviceSharding(device)
     spec = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=sh)
     params = jax.tree.map(spec, model.abstract_params())
     cache = jax.tree.map(spec, model.abstract_cache(B, W))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=sh)
     exe = ContinuousEngine(model, params, B, W)._executables
-    prefill = exe["prefill"].lower(params, {"tokens": i32(B, S)},
-                                   cache).compile()
+    prefill = exe["prefill"].lower(params, {"tokens": i32(B, S),
+                                            "slot": i32()}, cache).compile()
     tick = exe["decode_step"].lower(params, i32(B, 1), i32(B), cache).compile()
     out = {"params": tree_bytes(params), "cache": tree_bytes(cache),
            "prefill": executable_bytes(prefill),

@@ -22,7 +22,9 @@ def _json(*parts):
 
 def tiny_cell(config_name: str) -> dict:
     """A cell of the committed configuration and mix, cut to CPU size: the
-    same keys and code paths, small widths and lengths."""
+    same keys, family and code paths, small widths and lengths."""
+    import bench
+
     config = copy.deepcopy(_json("configs", f"{config_name}.json"))
     gqa = config["num_key_value_heads"] < config["num_attention_heads"]
     config.update(hidden_size=64, intermediate_size=128 if gqa else 96,
@@ -34,7 +36,8 @@ def tiny_cell(config_name: str) -> dict:
     mix["prompt"].update(median=12, min=6, max=24)
     mix["output"].update(median=5, min=2, max=12)
     return {"workload": {"name": f"{config_name}.tiny", "chips": 1},
-            "config": config, "traffic": mix, "limits": {"logit_gap": 0.3, "logit_err": 0.03},
+            "config": config, "family": bench.family(config["family"]),
+            "traffic": mix, "limits": {"logit_gap": 0.3, "logit_err": 0.03},
             "metrics": []}
 
 

@@ -115,10 +115,8 @@ def test_profiled_serve_has_bare_nested_engine_spans(tmp_path):
     from repro.serving.engine import ENGINE_SPANS, ContinuousEngine, Request
     from conftest import tiny_cell
 
-    import bench
-
     cell = tiny_cell("stablelm-3b")
-    model = build_model(bench.arch_config(cell["config"]))
+    model = build_model(cell["family"].program_config(cell["config"]))
     eng = ContinuousEngine(model, model.init(jax.random.key(0)), 2, 32)
     reqs = [Request(prompt=np.arange(1, 4 + i, dtype=np.int32),
                     max_new_tokens=2 + i) for i in range(4)]

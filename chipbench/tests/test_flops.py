@@ -1,18 +1,15 @@
-"""Useful-work counts against hand counts, for both configurations."""
-
-import json
-from pathlib import Path
+"""Useful-work counts of the dense family against hand counts, for both
+configurations."""
 
 import pytest
 
-import flops
+import bench
 
-CONFIGS = Path(__file__).resolve().parents[1] / "configs"
+flops = bench.family("dense")
 
 
 def config(name):
-    with open(CONFIGS / f"{name}.json") as f:
-        return json.load(f)
+    return bench.load_config(name)[0]
 
 
 # per layer: q and o at h*D = d, k and v at g*D, then the MLP's matrices
@@ -54,5 +51,7 @@ def test_act_elements(name):
     cfg = config(name)
     ff = cfg["intermediate_size"]
     # 2 prefills of 8 x 1920 rows and 5 ticks of 8 rows, 8 layers
-    assert flops.act_elements(cfg, 2, 5, 8, 1920) == \
+    counters = {"prefills": 2, "batch_steps": 7, "batch": 8,
+                "prefill_len": 1920}
+    assert flops.act_elements(cfg, counters) == \
         (2 * 8 * 1920 + 5 * 8) * ff * 8

@@ -93,9 +93,9 @@ def test_config_files_run_the_repo_config(name):
     lists as reduced."""
     from repro.models import get_config
 
-    config = json.loads((REPO / "chipbench" / "configs" /
-                         f"{name}.json").read_text())
-    ran = bench.arch_config(config)
+    config, family = bench.load_config(name)
+    assert config["family"] == "dense"
+    ran = family.program_config(config)
     repo = get_config(config["arch"])
     assert config["reduced"] == ["num_hidden_layers"]
     assert ran.replace(n_layers=repo.n_layers,
